@@ -419,7 +419,9 @@ def bench_fleet_service_openloop(full: bool):
       with deadlines expressed in units of the measured batch cost
       (``p99_over_deadline`` therefore transfers across machines — the
       gated p99 ceiling);
-    * ``_warmup``: AOT warmup cost per bucket and ``first_over_p50``,
+    * ``_warmup``: AOT warmup cost per bucket (compiled with the
+      persistent cache off, so a cache left by an earlier run does not
+      serve it) and ``first_over_p50``,
       the no-trace-spike acceptance figure (first post-warmup request vs
       steady-state p50);
     * ``_bursty``: ON/OFF bursts over drifted + stale-tolerant cells;
@@ -427,6 +429,7 @@ def bench_fleet_service_openloop(full: bool):
 
     Wall-clock rows feed the same-runner absolute gate as usual.
     """
+    from repro.compile_cache import compile_cache_off
     from repro.core import slice_round
     from repro.serve import (FleetControlService, ServiceConfig,
                              bursty_trace, drive, make_cells,
@@ -438,7 +441,8 @@ def bench_fleet_service_openloop(full: bool):
     probe = [slice_round(c, 0) for c in cells]
 
     svc = FleetControlService(ServiceConfig(max_batch=8))
-    wtimes = svc.warmup(probe[0], max_devices=n_dev)
+    with compile_cache_off():
+        wtimes = svc.warmup(probe[0], max_devices=n_dev)
     cap = measure_capacity(svc, probe)
     svc.stats.reset()
 
@@ -799,6 +803,9 @@ def main(argv=None) -> None:
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count="
             f"{args.host_devices}").strip()
+    from repro.compile_cache import enable_compile_cache
+
+    print(f"# compile cache: {enable_compile_cache()}")
     names = args.only.split(",") if args.only else list(BENCHES)
     unknown = [n for n in names if n not in BENCHES]
     if unknown:

@@ -56,8 +56,7 @@ def bench_single_fleet(scenario: str, n: int, chunk: int) -> None:
         ("fused flat (single launch)", jax.jit(solve_joint_fused)),
         ("alternating (paper Alg 2)", jax.jit(solve_joint)),
         ("bisection optimum (ours)", jax.jit(solve_joint_optimal)),
-        ("pallas kernel (interpret)",
-         lambda p: solve_joint_kernel(p, interpret=True)),
+        ("pallas kernel", solve_joint_kernel),
     ]
     for name, fn in solvers:
         sol, dt = _bench(lambda fn=fn: fn(prob))

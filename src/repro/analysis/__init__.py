@@ -25,8 +25,7 @@ from repro.analysis.prng import (PRNG_PROGRAMS, KeyReuseFinding,
                                  analyze_jaxpr, check_key_reuse)
 from repro.analysis.rank import (RankFinding, broadcastable_leaves,
                                  sweep_rank_contract)
-from repro.analysis.recompile import (CompileBudget, CompileBudgetExceeded,
-                                      compile_event_count)
+from repro.analysis.recompile import CompileBudget, CompileBudgetExceeded
 
 __all__ = [
     "HOT_PATHS",
@@ -39,7 +38,6 @@ __all__ = [
     "analyze_jaxpr",
     "broadcastable_leaves",
     "check_key_reuse",
-    "compile_event_count",
     "default_budgets_path",
     "load_budgets",
     "measure",

@@ -25,13 +25,16 @@ identifying the logical key:
 * consumption: ``random_bits`` (every jax.random distribution bottoms
   out there); two consumptions of one class = finding.
 
-Control flow: ``pjit``/``closed_call`` sub-jaxprs are walked inline
-with the caller's classes and a shared consumption counter.  ``cond``/
-``switch`` branches each see a *copy* of the counter and merge by max
-(branches are exclusive at runtime).  ``scan``/``while`` bodies run
-once with the carry's incoming classes; a key that is consumed in the
-body *and* carried through unchanged is flagged as cross-iteration
-reuse (iteration 2 would redraw with iteration 1's key).
+Control flow: any other eqn whose params hold a sub-jaxpr of matching
+arity (``jit``, ``closed_call``, ``custom_jvp_call``, ``checkpoint``,
+...) is walked inline with the caller's classes and a shared
+consumption counter.  ``cond``/``switch`` branches each see a *copy* of
+the counter and merge by max (branches are exclusive at runtime).
+``scan``/``while`` bodies run once with the carry's incoming classes; a
+key that is consumed in the body *and* is the same in every iteration
+(carried through unchanged, or a loop constant, which is what jax makes
+of such a carry) is flagged as cross-iteration reuse (iteration 2 would
+redraw with iteration 1's key).
 
 Limits (documented in docs/analysis.md): dynamic indexing into a split
 array yields a fresh conservative class (no reuse detectable through
@@ -48,7 +51,7 @@ from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 __all__ = [
     "KeyReuseFinding",
@@ -161,25 +164,30 @@ def _slice_descriptor(eqn) -> Optional[tuple]:
     return narrowed
 
 
+def _call_jaxpr(eqn):
+    """The sub-jaxpr a call-like eqn applies to its own operands, if any:
+    a ``Jaxpr``/``ClosedJaxpr`` param whose arity matches the eqn's.
+    Primitive names change across jax versions (``pjit`` became ``jit``),
+    so the walker keys on structure instead of a list of names."""
+    for val in eqn.params.values():
+        if isinstance(val, jax_core.ClosedJaxpr):
+            val = val.jaxpr
+        if (isinstance(val, jax_core.Jaxpr)
+                and len(val.invars) == len(eqn.invars)):
+            return val
+    return None
+
+
 def _walk(jaxpr, env: dict, state: _State, where: str) -> list:
     """Interpret ``jaxpr`` abstractly; returns outvar values."""
+    # jax reuses one traced jaxpr for repeated traces of a function (two
+    # scans over the same body), so an untracked variable names a key
+    # only within one walk
+    walk_id = state.fresh_class("walk")[2]
     for eqn in jaxpr.eqns:
         prim = eqn.primitive.name
         invals = [_read(env, v) for v in eqn.invars]
 
-        # higher-order primitives recurse and bind their own outvars
-        if prim in ("pjit", "closed_call", "core_call", "custom_jvp_call",
-                    "custom_vjp_call_jaxpr", "remat_call", "checkpoint"):
-            sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
-            if sub is not None:
-                sub_jaxpr = sub.jaxpr if hasattr(sub, "jaxpr") else sub
-                sub_env = dict(zip(sub_jaxpr.invars, invals, strict=False))
-                outs = _walk(sub_jaxpr, sub_env, state,
-                             f"{where}/{eqn.params.get('name', prim)}")
-                for var, val in zip(eqn.outvars, outs, strict=False):
-                    if val is not None:
-                        env[var] = val
-            continue
         if prim in ("cond", "switch"):
             branches = eqn.params.get("branches", ())
             branch_states, branch_outs = [], []
@@ -203,6 +211,16 @@ def _walk(jaxpr, env: dict, state: _State, where: str) -> list:
         if prim == "while":
             _walk_while(eqn, invals, env, state, where)
             continue
+        sub_jaxpr = _call_jaxpr(eqn)
+        if sub_jaxpr is not None:
+            # call-like higher-order primitive: walk inline, bind outvars
+            sub_env = dict(zip(sub_jaxpr.invars, invals, strict=False))
+            outs = _walk(sub_jaxpr, sub_env, state,
+                         f"{where}/{eqn.params.get('name', prim)}")
+            for var, val in zip(eqn.outvars, outs, strict=False):
+                if val is not None:
+                    env[var] = val
+            continue
 
         out = None
         if prim == "random_wrap":
@@ -213,7 +231,7 @@ def _walk(jaxpr, env: dict, state: _State, where: str) -> list:
             elif isinstance(src, jax_core.Literal):
                 out = ("wrap-lit", repr(getattr(src, "val", None)))
             else:
-                out = ("wrap", id(src))
+                out = ("wrap", walk_id, id(src))
         elif prim == "random_unwrap":
             out = invals[0]
         elif prim == "random_split":
@@ -275,6 +293,34 @@ def _carry_findings(state: _State, in_classes, out_classes, before: Counter,
                           "returned unchanged)")
 
 
+def _derives_from(cls, root) -> bool:
+    """``cls`` is ``root`` or a static descendant of it (split children,
+    literal ``fold_in``s, the split array itself)."""
+    if not isinstance(cls, tuple) or not isinstance(root, tuple):
+        return False
+    if cls[:len(root)] == root:
+        return True
+    return len(cls) > 1 and cls[0] == "splitarr" and _derives_from(cls[1],
+                                                                  root)
+
+
+def _invariant_findings(state: _State, consts, before: Counter,
+                        where: str) -> None:
+    """A key consumed in a loop body whose class derives statically from
+    a loop *constant* is the same key in every iteration — the same bug
+    as a carried key returned unchanged (jax may hoist such a carry into
+    a constant).  A ``fold_in`` of traced data (the iteration counter)
+    makes the class per-iteration, so it is not flagged."""
+    roots = [c for c in consts if c is not None]
+    for cls, n in list(state.consumed.items()):
+        if n <= before.get(cls, 0) or cls[0] == "carry-reuse":
+            continue
+        dynamic = any(isinstance(t, str) and t.startswith("dyn") for t in cls)
+        if not dynamic and any(_derives_from(cls, r) for r in roots):
+            state.consume(("carry-reuse",) + tuple(cls),
+                          f"{where} (loop-invariant key consumed in body)")
+
+
 def _walk_scan(eqn, invals, env, state: _State, where: str) -> None:
     body = eqn.params["jaxpr"].jaxpr
     n_consts = eqn.params["num_consts"]
@@ -288,6 +334,8 @@ def _walk_scan(eqn, invals, env, state: _State, where: str) -> None:
     before = Counter(state.consumed)
     outs = _walk(body, sub_env, state, f"{where}/scan")
     _carry_findings(state, carry, outs[:n_carry], before, f"{where}/scan")
+    if eqn.params["length"] > 1:
+        _invariant_findings(state, consts, before, f"{where}/scan")
     for var, val in zip(eqn.outvars[:n_carry], outs[:n_carry],
                         strict=False):
         if val is not None:
@@ -304,6 +352,7 @@ def _walk_while(eqn, invals, env, state: _State, where: str) -> None:
     before = Counter(state.consumed)
     outs = _walk(body, sub_env, state, f"{where}/while")
     _carry_findings(state, carry, outs, before, f"{where}/while")
+    _invariant_findings(state, consts, before, f"{where}/while")
     for var, val in zip(eqn.outvars, outs, strict=False):
         if val is not None:
             env[var] = val

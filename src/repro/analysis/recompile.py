@@ -11,11 +11,10 @@ Mechanism
 ---------
 ``jax.monitoring`` emits ``/jax/core/compile/backend_compile_duration``
 once per *actual* backend compilation — cache hits (both the in-process
-pjit cache and the persistent compilation cache) emit nothing, which is
-exactly the semantics a steady-state budget wants.  There is no
-listener-removal API on the floor jax (0.4.37), so one module-level
-listener appends to a process-global log forever and ``CompileBudget``
-scopes itself by log *indices*, never by mutating listener state.
+jit cache and the persistent compilation cache) emit nothing, which is
+exactly the semantics a steady-state budget wants.  Each
+``CompileBudget`` registers its own listener on entry and unregisters
+it on exit, so nothing outlives the block.
 
 Compiled-program names come from the ``jax._src.dispatch`` debug log
 ("Finished XLA compilation of jit(<name>) ...") — captured with a
@@ -45,44 +44,9 @@ import jax
 __all__ = [
     "CompileBudget",
     "CompileBudgetExceeded",
-    "compile_event_count",
 ]
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-# process-global, append-only compile log: one entry (duration seconds)
-# per backend compilation anywhere in the process
-_LOG: list[float] = []
-_LOCK = threading.Lock()
-_LISTENER_INSTALLED = False
-
-
-def _on_event_duration(event: str, duration: float, **_kw) -> None:
-    if event == _COMPILE_EVENT:
-        with _LOCK:
-            _LOG.append(duration)
-
-
-def _ensure_listener() -> None:
-    """Install the module-level monitoring listener exactly once.
-
-    jax 0.4.37 has ``clear_event_listeners`` but no selective removal,
-    so the listener is permanent; scoping happens via log indices.
-    """
-    global _LISTENER_INSTALLED
-    with _LOCK:
-        if _LISTENER_INSTALLED:
-            return
-        jax.monitoring.register_event_duration_secs_listener(
-            _on_event_duration)
-        _LISTENER_INSTALLED = True
-
-
-def compile_event_count() -> int:
-    """Total backend compilations observed so far in this process."""
-    _ensure_listener()
-    with _LOCK:
-        return len(_LOG)
 
 
 # "Finished XLA compilation of jit(solve) in 0.123 sec"
@@ -127,13 +91,12 @@ class CompileBudget:
         self.strict = strict
         self.count: int = 0
         self.names: list[str] = []
-        self._start = 0
+        self._lock = threading.Lock()
         self._handler: Optional[_NameCapture] = None
         self._prev_level: Optional[int] = None
         self._prev_propagate: Optional[bool] = None
 
     def __enter__(self) -> "CompileBudget":
-        _ensure_listener()
         logger = logging.getLogger(_DISPATCH_LOGGER)
         self._handler = _NameCapture()
         self._prev_level = logger.level
@@ -145,13 +108,17 @@ class CompileBudget:
         if logger.getEffectiveLevel() > logging.DEBUG:
             logger.setLevel(logging.DEBUG)
             logger.propagate = False
-        with _LOCK:
-            self._start = len(_LOG)
+        self.count = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
         return self
 
+    def _on_event(self, event: str, duration: float, **_kw) -> None:
+        if event == _COMPILE_EVENT:
+            with self._lock:
+                self.count += 1
+
     def __exit__(self, exc_type, exc, tb) -> None:
-        with _LOCK:
-            self.count = len(_LOG) - self._start
+        jax.monitoring.unregister_event_duration_listener(self._on_event)
         logger = logging.getLogger(_DISPATCH_LOGGER)
         if self._handler is not None:
             self.names = list(self._handler.names)

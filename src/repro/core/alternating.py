@@ -90,7 +90,7 @@ from repro.core.power import (
     energy_bound_ok,
     energy_gate_elements,
 )
-from repro.core.problem import WirelessFLProblem
+from repro.core.problem import WirelessFLProblem, accurate_expm1, accurate_log2
 from repro.core.selection import optimal_selection, selection_update_elements
 
 
@@ -335,7 +335,8 @@ def problem_elements(problem: WirelessFLProblem,
 def _fused_step(a: jax.Array, el: FleetElements, *, s_bits: float,
                 tau: float, p_max: float, power_solver: str,
                 faithful_eq13_typo: bool,
-                lam0: float | jax.Array = 1e-3
+                lam0: float | jax.Array = 1e-3,
+                expm1=accurate_expm1, log2=accurate_log2
                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One fused alternation on raw elements: power + gate + eq. 13.
 
@@ -346,13 +347,16 @@ def _fused_step(a: jax.Array, el: FleetElements, *, s_bits: float,
     whose ``lam0`` seed the warm-start path collapses).
 
     Returns ``(a_new, power, inner_iters)``; ``inner_iters`` is 0 in
-    analytic mode.
+    analytic mode.  ``expm1`` and ``log2`` replace the closed forms'
+    transcendentals in the analytic power update and the rate — the
+    Pallas kernel passes forms Mosaic lowers.
     """
     if el.sbits is not None:
         s_bits = el.sbits        # per-element bit-scaled payload
     if power_solver == "analytic":
         p, lam, feasible = analytic_power_elements(
-            a, el.pg, el.bw, s_bits=s_bits, tau=tau, p_max=p_max)
+            a, el.pg, el.bw, s_bits=s_bits, tau=tau, p_max=p_max,
+            expm1=expm1, log2=log2)
         inner = jnp.int32(0)
     elif power_solver == "dinkelbach":
         p, lam, inner, feasible = dinkelbach_power_elements(
@@ -360,7 +364,7 @@ def _fused_step(a: jax.Array, el: FleetElements, *, s_bits: float,
     else:
         raise ValueError(f"unknown power_solver {power_solver!r}")
     ok = energy_gate_elements(a, lam, el.emax, el.ec) & feasible
-    t = element_tx_time(p, el.pg, el.bw, s_bits=s_bits)
+    t = element_tx_time(p, el.pg, el.bw, s_bits=s_bits, log2=log2)
     a_new = selection_update_elements(p, t, el.emax, el.ec, tau=tau,
                                       s_bits=s_bits,
                                       faithful_eq13_typo=faithful_eq13_typo)
@@ -368,15 +372,16 @@ def _fused_step(a: jax.Array, el: FleetElements, *, s_bits: float,
 
 
 def fused_init(el: FleetElements, *, s_bits: float, tau: float,
-               p_max: float, faithful_eq13_typo: bool = False
-               ) -> tuple[jax.Array, jax.Array]:
+               p_max: float, faithful_eq13_typo: bool = False,
+               log2=accurate_log2) -> tuple[jax.Array, jax.Array]:
     """Feasible (a^0, P^0) on raw elements: transmit at P^max, a^0 from
     eq. (13) — the element form of ``_init_state``.  Shared with the
-    Pallas kernel so the two paths cannot drift."""
+    Pallas kernel so the two paths cannot drift (``log2`` as in
+    ``_fused_step``)."""
     if el.sbits is not None:
         s_bits = el.sbits
     p0 = jnp.full(el.pg.shape, p_max)
-    t0 = element_tx_time(p0, el.pg, el.bw, s_bits=s_bits)
+    t0 = element_tx_time(p0, el.pg, el.bw, s_bits=s_bits, log2=log2)
     a0 = selection_update_elements(p0, t0, el.emax, el.ec, tau=tau,
                                    s_bits=s_bits,
                                    faithful_eq13_typo=faithful_eq13_typo)

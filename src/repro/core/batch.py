@@ -434,10 +434,13 @@ def solve_joint_batch(batch: ProblemBatch,
         (``solve_joint_optimal``).
       * ``"kernel"``       — the Pallas ``selection_solve`` kernel over the
         flattened ``[B * N_max]`` element set (solves the same bisection
-        problem as ``"optimal"``; ``interpret=True`` runs it off-TPU).
+        problem as ``"optimal"``).
       * ``"fused_kernel"`` — the Pallas ``fused_solve`` kernel: the fused
-        alternating fixed point, whole tiles VMEM-resident
-        (``interpret=True`` runs it off-TPU).
+        alternating fixed point, whole tiles VMEM-resident.
+
+    The kernel methods compile on a TPU and run in the Pallas interpreter
+    elsewhere; ``interpret=True`` forces the interpreter
+    (``repro.kernels.resolve_interpret``).
 
     ``power_solver`` (default: ``"dinkelbach"`` for ``"alternating"``,
     ``"analytic"`` — the bit-identical closed form — for the fused
@@ -446,8 +449,9 @@ def solve_joint_batch(batch: ProblemBatch,
     (the other methods compute the exact per-element optimum directly);
     requesting the eq.-13 typo with them is an error rather than a
     silent mismatch.  ``"fused_kernel"`` runs ``max_iters`` fixed
-    iterations (no ``eps`` early-exit — the iteration is stationary past
-    its fixed point) and rejects ``power_solver="dinkelbach"``.
+    iterations (no ``eps`` early-exit — each element freezes at its first
+    step below the default ``eps``, 1e-7) and rejects
+    ``power_solver="dinkelbach"``.
 
     ``shard=True`` splits the batch axis (the element axis for
     ``"fused"``) over the local devices with a ``NamedSharding`` before
@@ -523,15 +527,15 @@ def solve_joint_batch(batch: ProblemBatch,
         batch = shard_batch(batch, mesh)
     if method == "kernel":
         from repro.kernels.selection_solve.ops import solve_joint_kernel_batch
-        return solve_joint_kernel_batch(
-            batch, interpret=True if interpret is None else interpret)
+        return solve_joint_kernel_batch(batch, interpret=interpret)
     if method == "fused_kernel":
         from repro.kernels.selection_solve.ops import solve_joint_fused_kernel_batch
         # the kernel runs its full iteration budget unconditionally (fixed
-        # trip count, stationary past the fixed point), so ``eps`` has no
-        # kernel analogue; ``max_iters`` maps onto that budget.
+        # trip count, each element frozen at its first step below the
+        # default eps), so ``eps`` is not passed on; ``max_iters`` maps
+        # onto that budget.
         return solve_joint_fused_kernel_batch(
             batch, n_iters=max_iters, faithful_eq13_typo=faithful_eq13_typo,
-            interpret=True if interpret is None else interpret)
+            interpret=interpret)
     return _solve_batch_vmapped(batch, method, power_solver,
                                 faithful_eq13_typo, eps, max_iters, init)

@@ -38,8 +38,15 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.problem import LN2, WirelessFLProblem, _bcast_like
+from repro.core.problem import (
+    LN2,
+    WirelessFLProblem,
+    _bcast_like,
+    accurate_expm1,
+    accurate_log2,
+)
 
 _A_FLOOR = 1e-12   # guards the a -> 0 division in P*(lambda)
 
@@ -53,43 +60,49 @@ class PowerSolution(NamedTuple):
 
 # -------------------------------------------------------- element level
 
-def element_p_min(a, pg, bw, *, s_bits: float, tau: float) -> jax.Array:
+def element_p_min(a, pg, bw, *, s_bits: float, tau: float,
+                  expm1=accurate_expm1) -> jax.Array:
     """P^min_ik = (2^{a S / (B tau)} - 1) / pg, exponent-clamped (eq. 7c).
 
-    Mirrors ``WirelessFLProblem.p_min`` on raw element arrays.
+    Mirrors ``WirelessFLProblem.p_min`` on raw element arrays.  ``expm1``
+    lets a Pallas body substitute a form its compiler lowers.
     """
     exponent = jnp.minimum(a * s_bits / (bw * tau), 120.0)
-    num = jnp.expm1(exponent * LN2)
+    num = expm1(exponent * LN2)
     # zero/NaN gain (deep fade to zero, corrupted channel): P^min = inf is
     # the infeasible-device gate — the raw division emits 0 / 0 = NaN at
     # a = 0 and poisons the fused while-loop (docs/robustness.md)
     return jnp.where(pg > 0, num / jnp.where(pg > 0, pg, 1.0), jnp.inf)
 
 
-def element_tx_time(power, pg, bw, *, s_bits: float) -> jax.Array:
-    """T_ik(P) = S / r_ik(P) with r = B log2(1 + P pg)  (eq. 1)."""
-    return s_bits / jnp.maximum(bw * jnp.log2(1.0 + power * pg), 1e-30)
+def element_tx_time(power, pg, bw, *, s_bits: float,
+                    log2=accurate_log2) -> jax.Array:
+    """T_ik(P) = S / r_ik(P) with r = B log2(1 + P pg)  (eq. 1).  ``log2``
+    lets a Pallas body substitute a form its compiler lowers."""
+    return s_bits / jnp.maximum(bw * log2(1.0 + power * pg), 1e-30)
 
 
-def _element_lam(a, power, pg, bw, *, s_bits: float) -> jax.Array:
+def _element_lam(a, power, pg, bw, *, s_bits: float,
+                 log2=accurate_log2) -> jax.Array:
     """Objective (9a): a P T(P), defined 0 where a = 0 (rate(0) = 0)."""
-    t = element_tx_time(power, pg, bw, s_bits=s_bits)
+    t = element_tx_time(power, pg, bw, s_bits=s_bits, log2=log2)
     return jnp.where(a > 0, jnp.maximum(a, _A_FLOOR) * power * t, 0.0)
 
 
 def analytic_power_elements(a, pg, bw, *, s_bits: float, tau: float,
-                            p_max: float
+                            p_max: float, expm1=accurate_expm1,
+                            log2=accurate_log2
                             ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Closed-form optimum of (9) per element: P* = clip(P^min(a), 0, P^max).
 
     Returns ``(power, lam, feasible)`` with ``lam`` the objective (9a) at
     the optimum — exactly what Dinkelbach's lambda converges to.
     """
-    p_min = jnp.clip(element_p_min(a, pg, bw, s_bits=s_bits, tau=tau),
-                     0.0, None)
+    p_min = jnp.clip(element_p_min(a, pg, bw, s_bits=s_bits, tau=tau,
+                                   expm1=expm1), 0.0, None)
     feasible = p_min <= p_max * (1 + 1e-6)
     p = jnp.minimum(p_min, p_max)
-    return p, _element_lam(a, p, pg, bw, s_bits=s_bits), feasible
+    return p, _element_lam(a, p, pg, bw, s_bits=s_bits, log2=log2), feasible
 
 
 def dinkelbach_power_elements(a, pg, bw, *, s_bits: float, tau: float,
@@ -231,3 +244,46 @@ def energy_bound_ok(problem: WirelessFLProblem, a: jax.Array, sol: PowerSolution
     ec = _bcast_like(problem.compute_energy(), rank)
     emax = _bcast_like(problem.energy_budget_j, rank)
     return energy_gate_elements(_bcast_like(a, rank), sol.lam, emax, ec)
+
+
+# Two f32 programs that form x = a S ln2 / (B tau) through a handful of
+# roundings (a S, B tau, the quotient, the ln 2 product) may differ by a
+# few f32 epsilons of x; powers at the floor (a = 0) differ by at most
+# an absolute 1e-6 W.
+_AGREEMENT_ULPS = 4.0
+_AGREEMENT_ATOL = 1e-6
+
+
+def power_agreement_tol(problem: WirelessFLProblem, a, power, *,
+                        a_other=None) -> np.ndarray:
+    """Per-element bound on ``|P_1 - P_2|`` between two f32 solves that
+    reach the same fixed point ``(a, power)`` through different programs
+    (chunked vs unchunked, XLA vs Pallas, CPU vs TPU).
+
+    ``P^min = expm1(x) / pg`` with ``x = a S_i ln2 / (B_i tau)``.  A
+    relative error ``r`` in ``x`` moves ``P^min`` by ``kappa(x) r``, where
+    ``kappa(x) = x e^x / expm1(x)`` is the condition number of expm1: 1
+    at ``x = 0`` and about ``x`` for large ``x``.  Rounding gives
+    ``r = _AGREEMENT_ULPS`` f32 epsilons.  Where the two solves'
+    selections differ by more than rounding (a fixed iteration count
+    against a converged loop, or an element on the ``P^max`` feasibility
+    edge whose gate tips the other way), pass the other solve's ``a`` as
+    ``a_other``: its relative distance to ``a`` is a relative error in
+    ``x`` too.  Returns ``_AGREEMENT_ATOL + kappa(x) * (r + |a - a_other|
+    / a) * |power|`` in float64, shaped like ``a``.
+    """
+    a = np.asarray(a, np.float64)
+    rank = a.ndim
+    s = problem.payload_bits(rank)
+    bw = _bcast_like(problem.bandwidth_hz, rank)
+    x = np.minimum(a * np.asarray(s, np.float64)
+                   / (np.asarray(bw, np.float64) * problem.tau_th),
+                   120.0) * LN2
+    safe = np.where(x > 0, x, 1.0)
+    kappa = np.where(x > 0, safe * np.exp(safe) / np.expm1(safe), 1.0)
+    rel = _AGREEMENT_ULPS * float(np.finfo(np.float32).eps)
+    if a_other is not None:
+        a_other = np.asarray(a_other, np.float64)
+        top = np.maximum(a, a_other)
+        rel = rel + np.abs(a - a_other) / np.where(top > 0, top, 1.0)
+    return _AGREEMENT_ATOL + kappa * rel * np.abs(np.asarray(power, np.float64))

@@ -50,6 +50,33 @@ import numpy as np
 
 LN2 = float(np.log(2.0))
 
+
+# The closed forms P^min = expm1(x) / pg and r = B log2(1 + P pg) are
+# inverse to each other: at P = P^min(a) the eq.-13 time term returns a
+# exactly, so the selection iterate is stationary there only as far as
+# the two transcendentals invert each other.  A TPU v5e's default f32
+# transcendentals are approximations (exp up to 57 ulp, expm1 up to 986
+# ulp below x = ln 2, log up to 2206 ulp); at that noise a cold batch of
+# 100-device cells did not meet the fused solve's 1e-7 stopping rule in
+# its 50 steps.  The closed forms therefore ask XLA for its most
+# accurate implementation (within 1.5 ulp on the v5e; one step to
+# converge, as on the CPU), which on the CPU is the default one
+# (bit-identical results).
+_ACCURATE = jax.lax.AccuracyMode.HIGHEST
+
+
+def accurate_expm1(x):
+    """``expm1`` at XLA's highest accuracy (see above)."""
+    return jax.lax.expm1(x, accuracy=_ACCURATE)
+
+
+def accurate_log2(x):
+    """``log2`` at XLA's highest accuracy; the same ``log(x) / ln 2`` as
+    ``jnp.log2``."""
+    x = jnp.asarray(x)
+    return jax.lax.log(x, accuracy=_ACCURATE) / np.asarray(LN2, x.dtype)
+
+
 # Uncompressed payload of the paper's model: 199_210 fp32 parameters.
 # The single source of truth for the magic number — examples, benchmarks
 # and the bit-allocation code all import it from here.
@@ -170,7 +197,7 @@ class WirelessFLProblem:
         bw = self.bandwidth_hz
         if max(p.ndim, pg.ndim) > bw.ndim:
             bw = bw[:, None]
-        return bw * jnp.log2(1.0 + p * pg)
+        return bw * accurate_log2(1.0 + p * pg)
 
     def payload_bits(self, rank: int = 1):
         """Effective uplink payload S_i = S * b_i / 32 in bits.
@@ -235,7 +262,7 @@ class WirelessFLProblem:
         # huge-but-finite P^min (> p_max), which downstream logic treats as
         # "infeasible at this a" rather than producing NaNs.
         exponent = jnp.minimum(exponent, 120.0)
-        num = jnp.expm1(exponent * LN2)
+        num = accurate_expm1(exponent * LN2)
         # zero/NaN gain (deep fade to zero, corrupted channel): P^min = inf
         # is the infeasible-device gate; the unguarded num / pg emits NaN
         # at a = 0 (0 / 0) and poisons every downstream update

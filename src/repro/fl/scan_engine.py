@@ -64,6 +64,7 @@ from repro.core.schedulers import (
 )
 from repro.data.synthetic import Dataset
 from repro.fl.engine import FLConfig, FLHistory, FLResult, _quantize_tree
+from repro.kernels import resolve_interpret
 from repro.kernels.masked_aggregate.ops import (masked_aggregate_pytree,
                                                 quantized_aggregate_pytree)
 from repro.models import cnn
@@ -528,11 +529,9 @@ def run_fl_sweep(plans: TrajectoryPlan,
     if plans.n_rounds != config.n_rounds:
         raise ValueError(f"plan has {plans.n_rounds} rounds, "
                          f"config.n_rounds={config.n_rounds}")
-    backend = jax.default_backend()
-    if kernel_interpret is None:
-        kernel_interpret = backend != "tpu"
+    kernel_interpret = resolve_interpret(kernel_interpret)
     if donate_params is None:
-        donate_params = backend not in ("cpu",)
+        donate_params = jax.default_backend() != "cpu"
     static = _Static(
         n_rounds=config.n_rounds, batch_per_client=config.batch_per_client,
         aggregate=config.aggregate, renormalize=config.renormalize,

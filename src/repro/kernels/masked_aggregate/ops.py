@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import resolve_interpret
 from repro.kernels.masked_aggregate.kernel import (
     CLIENT_BLK, LANE_BLK, masked_aggregate_tiled,
     quantized_masked_aggregate_tiled)
@@ -20,11 +21,10 @@ def masked_aggregate(gstack: jax.Array, coef: jax.Array,
                      interpret: bool | None = None) -> jax.Array:
     """gstack [N, ...] -> [...] (leading client axis reduced).
 
-    ``interpret=None`` auto-selects: the compiled Pallas kernel on TPU,
-    interpret mode (functional check) everywhere else.
+    ``interpret=None`` compiles the kernel on TPU and interprets it
+    elsewhere (``repro.kernels.resolve_interpret``).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n = gstack.shape[0]
     lead_shape = gstack.shape[1:]
     d = int(np.prod(lead_shape))
@@ -51,8 +51,7 @@ def quantized_masked_aggregate(gstack: jax.Array, coef: jax.Array,
     quantisation fused into the masked sum.  ``bits`` is a scalar or [N]
     array; ``noise`` is uniform(0,1) of gstack's shape (precomputed so the
     kernel matches the unfused quantise-then-sum path exactly)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n = gstack.shape[0]
     lead_shape = gstack.shape[1:]
     d = int(np.prod(lead_shape))

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.core.alternating import JointSolution
 from repro.core.problem import WirelessFLProblem
+from repro.kernels import resolve_interpret
 
 _ROWS_BLK = 256 * 128   # elements per kernel tile: (256, 128) f32
 
@@ -35,7 +36,7 @@ def _bcast_rounds(x: jax.Array, like: jax.Array) -> jax.Array:
 
 
 def _solve_elements(problem: WirelessFLProblem, pg: jax.Array,
-                    interpret: bool, tiled_fn=None,
+                    interpret: bool | None, tiled_fn=None,
                     **tiled_kw) -> tuple[jax.Array, jax.Array]:
     """Run a tiled kernel over every element of ``pg`` (any shape),
     returning (a*, P*) with ``pg``'s shape.  Scalar constraint data is
@@ -55,21 +56,22 @@ def _solve_elements(problem: WirelessFLProblem, pg: jax.Array,
     args = [_pack(v, n_pad) for v in (pg, bw, emax, ec)]
     a, p = tiled_fn(
         *args, s_bits=problem.grad_size_bits, tau=problem.tau_th,
-        p_max=problem.p_max, interpret=interpret, **tiled_kw)
+        p_max=problem.p_max, interpret=resolve_interpret(interpret),
+        **tiled_kw)
     return (a.reshape(-1)[:n].reshape(pg.shape),
             p.reshape(-1)[:n].reshape(pg.shape))
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def solve_joint_kernel(problem: WirelessFLProblem,
-                       interpret: bool = True) -> JointSolution:
+                       interpret: bool | None = None) -> JointSolution:
     a, p = _solve_elements(problem, problem.path_gain(), interpret)
     return JointSolution(a=a, power=p, objective=problem.objective(a),
                          n_iters=jnp.int32(60), converged=jnp.asarray(True))
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def solve_joint_kernel_batch(batch, interpret: bool = True):
+def solve_joint_kernel_batch(batch, interpret: bool | None = None):
     """Pallas fast path for ``core.batch.solve_joint_batch``.
 
     Flattens the [B, N_max] (or [B, N_max, K]) element set into one tiled
@@ -99,7 +101,7 @@ def solve_joint_kernel_batch(batch, interpret: bool = True):
 def solve_joint_fused_kernel(problem: WirelessFLProblem,
                              n_iters: int = 50,
                              faithful_eq13_typo: bool = False,
-                             interpret: bool = True) -> JointSolution:
+                             interpret: bool | None = None) -> JointSolution:
     """Pallas fused Algorithm-2 solve for one problem (drop-in for
     ``core.alternating.solve_joint_fused``; agreement <= 1e-5)."""
     from repro.kernels.selection_solve.kernel import fused_solve_tiled
@@ -116,7 +118,7 @@ def solve_joint_fused_kernel(problem: WirelessFLProblem,
                                    "interpret"))
 def solve_joint_fused_kernel_batch(batch, n_iters: int = 50,
                                    faithful_eq13_typo: bool = False,
-                                   interpret: bool = True):
+                                   interpret: bool | None = None):
     """Pallas fused path for ``core.batch.solve_joint_batch``: the whole
     [B * N_max (* K)] element set runs the alternating fixed point in one
     tiled launch, every iterate VMEM-resident."""

@@ -6,6 +6,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.problem import accurate_expm1
 from repro.kernels.selection_solve.kernel import (
     LN2,
     N_ALT,
@@ -16,7 +17,7 @@ from repro.kernels.selection_solve.kernel import (
 
 def _feasible(a, pg, bw, emax, ec, s_bits, tau, p_max):
     expo = jnp.minimum(a * s_bits / (bw * tau), 120.0)
-    p_min = jnp.expm1(expo * LN2) / pg
+    p_min = accurate_expm1(expo * LN2) / pg
     return (p_min <= p_max) & (tau * p_min + a * ec <= emax)
 
 
@@ -35,7 +36,7 @@ def selection_solve_ref(pg, bw, emax, ec, *, s_bits: float, tau: float,
     lo, hi = jax.lax.fori_loop(0, N_BISECT, body, (lo, hi))
     a = jnp.where(feas1, 1.0, lo)
     expo = jnp.minimum(a * s_bits / (bw * tau), 120.0)
-    p = jnp.clip(jnp.expm1(expo * LN2) / pg, 0.0, p_max)
+    p = jnp.clip(accurate_expm1(expo * LN2) / pg, 0.0, p_max)
     return a, p
 
 

@@ -26,10 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x; pick
-# whichever this install provides.
-_compiler_params = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, dskip_ref,
             y_ref, state_scr, *, n_chunks):
@@ -104,7 +100,7 @@ def ssd_scan_tiled(x, dt, a, b_mat, c_mat, d_skip, *, chunk: int,
         out_specs=pl.BlockSpec((1, chunk, p), lambda i, c: (i, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt[..., None], a[:, None], b_mat, c_mat, d_skip[:, None])

@@ -7,6 +7,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.swa_decode.kernel import swa_decode_tiled
 
 
@@ -14,7 +15,7 @@ from repro.kernels.swa_decode.kernel import swa_decode_tiled
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      pos_buf: jax.Array, qpos: jax.Array,
                      *, window: int | None, n_heads: int,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """q [B,1,H,dh]; k/v [B,W,Hkv,dh]; returns [B,1,H,dh]."""
     bsz, _, h, dh = q.shape
     hkv = k.shape[2]
@@ -25,5 +26,6 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qg = (q[:, 0] * dh ** -0.5).reshape(bsz, hkv, g, dh)
     out = swa_decode_tiled(qg, k, v, pos_buf.astype(jnp.int32),
                            qpos.astype(jnp.int32), window=window,
-                           kv_blk=kv_blk, interpret=interpret)
+                           kv_blk=kv_blk,
+                           interpret=resolve_interpret(interpret))
     return out.reshape(bsz, 1, h, dh)

@@ -15,6 +15,7 @@ from repro.core import (
     solve_joint_fused,
     stack_problems,
 )
+from repro.core.power import power_agreement_tol
 
 TOL = 1e-5
 
@@ -74,5 +75,8 @@ def test_fused_chunked_matches_unchunked(problem, chunk):
     sol = solve_joint_fused(problem, chunk_elements=chunk)
     np.testing.assert_allclose(np.asarray(sol.a), np.asarray(ref.a),
                                atol=1e-6, rtol=0)
-    np.testing.assert_allclose(np.asarray(sol.power), np.asarray(ref.power),
-                               atol=1e-6, rtol=1e-6)
+    # two XLA programs of one fixed point: P^min = expm1(x)/pg amplifies
+    # f32 rounding of x about x-fold (power_agreement_tol derives it)
+    tol = power_agreement_tol(problem, ref.a, ref.power)
+    assert np.all(np.abs(np.asarray(sol.power, np.float64)
+                         - np.asarray(ref.power, np.float64)) <= tol)

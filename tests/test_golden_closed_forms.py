@@ -139,3 +139,46 @@ class TestEnergyGate:
         np.testing.assert_array_equal(
             np.asarray(energy_gate_elements(a, lam, emax, ec)),
             [True, False, True])
+
+
+class TestPowerAgreementTol:
+    """``power_agreement_tol`` on the three hand-computed elements above:
+    x = a S ln2 / (B tau) and kappa(x) = x e^x / expm1(x).
+      el0  x = 0.5 ln2 = 0.34657359,  kappa = 0.34657359 * 1.41421356
+                                              / 0.41421356 = 1.18328...
+      el1  x = 10 ln2 = 6.93147181,   kappa = 6.93147181 * 1024 / 1023
+                                            = 6.93824743...
+      el2  a = 0: kappa = 1 and P = 0, so only atol remains."""
+
+    KAPPA = np.array([1.1832840, 6.9382474, 1.0])
+
+    def _problem(self):
+        import dataclasses
+
+        import jax.numpy as jnp
+
+        from repro.core.problem import sample_problem
+
+        return dataclasses.replace(sample_problem(0, 3),
+                                   bandwidth_hz=jnp.asarray(BW),
+                                   grad_size_bits=S_BITS, tau_th=TAU)
+
+    def test_rounding_term(self):
+        from repro.core.power import power_agreement_tol
+
+        got = power_agreement_tol(self._problem(), A, P_GOLD)
+        eps = np.finfo(np.float32).eps
+        want = 1e-6 + 4 * eps * self.KAPPA * np.asarray(P_GOLD)
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+
+    def test_selection_gap_term(self):
+        from repro.core.power import power_agreement_tol
+
+        a_other = A * np.float32(1.0 + 1e-3)
+        base = power_agreement_tol(self._problem(), A, P_GOLD)
+        got = power_agreement_tol(self._problem(), A, P_GOLD,
+                                  a_other=a_other)
+        rel = np.where(A > 0, (a_other - A) / np.maximum(a_other, 1e-30), 0)
+        np.testing.assert_allclose(got - base,
+                                   self.KAPPA * rel * np.asarray(P_GOLD),
+                                   rtol=1e-5, atol=1e-12)

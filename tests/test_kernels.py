@@ -55,6 +55,24 @@ class TestFusedSolveKernel:
         np.testing.assert_allclose(np.asarray(p_k), np.asarray(p_r),
                                    rtol=1e-5, atol=1e-8)
 
+    def test_stationary_past_fixed_point(self):
+        """Each element freezes at its first sub-EPS step, so past that
+        step a longer trip count changes no bit.  (Without the freeze,
+        rounding keeps time-bound elements of this fleet moving for 10+
+        steps, and the trip count decides where they stop.)"""
+        from repro.kernels.selection_solve.kernel import fused_solve_tiled
+        rng = np.random.default_rng(1025)
+        shape = (1024, 128)
+        pg = jnp.asarray(rng.uniform(1e4, 1e8, shape), jnp.float32)
+        bw = jnp.asarray(rng.uniform(5e4, 5e6, shape), jnp.float32)
+        emax = jnp.asarray(np.exp(rng.uniform(-7, 4, shape)), jnp.float32)
+        ec = jnp.asarray(np.exp(rng.uniform(-8, -2, shape)), jnp.float32)
+        kw = dict(s_bits=6.4e6, tau=0.08, p_max=1.0, interpret=True)
+        short = fused_solve_tiled(pg, bw, emax, ec, n_iters=10, **kw)
+        long = fused_solve_tiled(pg, bw, emax, ec, n_iters=50, **kw)
+        for x, y in zip(short, long):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
     def test_ops_wrapper_matches_solve_joint(self):
         from repro.core import solve_joint
         from repro.kernels.selection_solve.ops import solve_joint_fused_kernel
@@ -208,3 +226,41 @@ class TestSSDScanKernel:
                              interpret=True)
         np.testing.assert_allclose(np.asarray(y_kernel), np.asarray(y_model),
                                    rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------------------- interpret rule
+
+def test_interpret_rule_follows_backend():
+    """One rule: ``None`` compiles on a TPU and interprets elsewhere; an
+    explicit bool always wins.  The library's kernel entry points all
+    default to ``None``."""
+    import inspect
+
+    import jax
+
+    from repro.kernels import resolve_interpret
+    from repro.kernels.masked_aggregate import ops as agg_ops
+    from repro.kernels.selection_solve import ops as sel_ops
+
+    assert resolve_interpret(None) is (jax.default_backend() != "tpu")
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
+    for fn in (sel_ops.solve_joint_kernel, sel_ops.solve_joint_kernel_batch,
+               sel_ops.solve_joint_fused_kernel,
+               sel_ops.solve_joint_fused_kernel_batch,
+               agg_ops.masked_aggregate, agg_ops.quantized_masked_aggregate):
+        default = inspect.signature(fn).parameters["interpret"].default
+        assert default is None, fn
+
+
+def test_fused_kernel_default_interpret_matches_explicit():
+    """``interpret=None`` off-TPU runs the interpreter: same answer as an
+    explicit ``interpret=True``."""
+    from repro.kernels.selection_solve.ops import solve_joint_fused_kernel
+
+    prob = sample_problem(7, 40)
+    auto = solve_joint_fused_kernel(prob)
+    forced = solve_joint_fused_kernel(prob, interpret=True)
+    np.testing.assert_array_equal(np.asarray(auto.a), np.asarray(forced.a))
+    np.testing.assert_array_equal(np.asarray(auto.power),
+                                  np.asarray(forced.power))
