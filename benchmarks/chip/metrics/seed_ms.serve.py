@@ -1,0 +1,14 @@
+"""Host time of warm seeding per answered batch, in ms: the seconds of
+the program's ``fleet_service.seed`` spans in the trace (cache
+lookups, packing the seeds, their upload) over the count of
+``fleet_service.serve`` spans (one per answered batch)."""
+
+SPAN = "fleet_service.seed"
+BATCH = "fleet_service.serve"
+
+
+def read(run):
+    batches = run.trace.host_seconds([BATCH])[1]
+    if not batches:
+        return None
+    return 1e3 * run.trace.host_seconds([SPAN])[0] / batches

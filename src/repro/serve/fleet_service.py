@@ -41,10 +41,16 @@ recomputation the warm-start path can skip.
   notes), keyed both on quantised problem features
   (:func:`quantized_problem_key`) and per cell;
 * **accounting** — sustained solves/sec, p50/p99 request latency,
-  deadline-miss rate, preemption and close-reason counters, cache hit
-  rates and inner-iteration counts (:class:`ServiceStats`; the
+  deadline-miss rate, queue wait, preemption and close-reason counters,
+  cache hit rates and inner-iteration counts (:class:`ServiceStats`; the
   ``fleet_service_throughput`` / ``fleet_service_openloop`` benchmarks
-  and CI gate consume these).
+  and CI gate consume these);
+* **profiler spans** — while a ``jax.profiler`` trace is being taken,
+  ``submit`` and every answered batch write ``fleet_service.*``
+  annotations (``SPAN_*``) on the trace's host clock: a request's intake
+  and key, a batch's packing, seeding, solve, read back and responses.
+  With no trace running each call pays one ``is_enabled`` check and
+  builds no annotation (``docs/serving.md``, "Observing the service").
 
 The loop stays deliberately synchronous — the unit of work is one
 compiled batched solve, and a thread pump around it would only blur the
@@ -62,6 +68,7 @@ consistently per service instance.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import hashlib
 import time
@@ -70,6 +77,7 @@ from typing import Hashable, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.alternating import JointSolution, WarmStart
 from repro.core.batch import (
@@ -95,6 +103,37 @@ CLOSE_FULL = "full"          # the bucket's instance slots are exhausted
 CLOSE_DEADLINE = "deadline"  # tightest budget ~ the bucket's solve cost
 CLOSE_LINGER = "linger"      # oldest request hit the linger latency bound
 CLOSE_FORCED = "forced"      # explicit step()/run() drain
+
+# profiler spans, written only while a jax.profiler trace is being taken
+SPAN_SUBMIT = "fleet_service.submit"      # one request's intake (seq, cell,
+#                                           lane)
+SPAN_KEY = "fleet_service.key"            # its quantised feature key
+SPAN_SERVE = "fleet_service.serve"        # one answered batch, solved or
+#                                           shed (batch, size, bucket,
+#                                           reason, lane, seqs)
+SPAN_PACK = "fleet_service.pack"          # stack + pad the batch
+SPAN_SEED = "fleet_service.seed"          # warm seeds looked up and uploaded
+SPAN_SOLVE = "fleet_service.solve"        # the batch solve, to completion
+SPAN_READBACK = "fleet_service.readback"  # device -> host reads
+SPAN_RESPOND = "fleet_service.respond"    # responses, caches, accounting
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _span(on: bool, name: str):
+    """Profiler span ``name`` when ``on`` (a trace is running), else a
+    shared no-op: with tracing off no annotation is built."""
+    return TraceAnnotation(name) if on else _NO_SPAN
+
+
+def _lane(priority: bool) -> str:
+    return "priority" if priority else "normal"
+
+
+def _queue_wait_us(reqs, t_close: float) -> int:
+    """Whole microseconds ``reqs`` waited from submit to their batch's
+    close stamp, summed (integers per request: deterministic sums)."""
+    return sum(round((t_close - r.t_submit) * 1e6) for r in reqs)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -201,7 +240,14 @@ class BatchRecord(NamedTuple):
 
 
 class ServiceStats:
-    """Steady-state throughput/latency counters (host-side, cheap)."""
+    """Steady-state throughput/latency counters (host-side, cheap).
+
+    ``solve_seconds`` is the host time of each solved batch from packing
+    through the convergence check (the ``t0..t1`` the close policy's
+    cost model observes), plus each metro tick's wall time: not the
+    device solve alone.  The ``fleet_service.*`` profiler spans split it
+    (``docs/serving.md``, "Observing the service").
+    """
 
     def __init__(self, latency_window: int = 8192):
         self._window = latency_window
@@ -218,6 +264,8 @@ class ServiceStats:
         self.n_priority = 0
         self.n_deadline_misses = 0
         self.n_preemptions = 0
+        self.queue_wait_us = 0        # sum of (close stamp - t_submit)
+        #                               over answered requests
         self.closes = collections.Counter()
         self.solve_seconds = 0.0
         self.outer_iters = 0
@@ -239,9 +287,11 @@ class ServiceStats:
     def record_batch(self, responses, solve_s: float, outer: int,
                      inner: int, reason: str = CLOSE_FORCED,
                      preempted: bool = False,
-                     retried: bool = False) -> None:
+                     retried: bool = False,
+                     queue_wait_us: int = 0) -> None:
         self.n_batches += 1
         self.n_solved += len(responses)
+        self.queue_wait_us += queue_wait_us
         self.solve_seconds += solve_s
         self.outer_iters += outer
         self.inner_iters += inner
@@ -270,6 +320,8 @@ class ServiceStats:
     # ---- derived figures ------------------------------------------------
     @property
     def solves_per_sec(self) -> float:
+        """Requests answered per second of ``solve_seconds`` (packing
+        through the convergence check, not the device solve alone)."""
         return self.n_solved / self.solve_seconds if self.solve_seconds else 0.0
 
     def latency_percentile(self, q: float) -> float:
@@ -318,6 +370,7 @@ class ServiceStats:
             "priority": self.n_priority,
             "deadline_misses": self.n_deadline_misses,
             "preemptions": self.n_preemptions,
+            "queue_wait_us": self.queue_wait_us,
             "closes": dict(self.closes),
             "outer_iters": self.outer_iters,
             "inner_iters": self.inner_iters,
@@ -333,6 +386,10 @@ class ServiceStats:
         }
 
     def summary(self) -> dict:
+        """Counters and derived figures.  ``solves_per_sec`` divides by
+        ``solve_seconds``: each batch's host time from packing through
+        the convergence check, which the ``fleet_service.*`` spans
+        split into pack, seed, solve and read back."""
         return {
             "requests": self.n_requests,
             "solved": self.n_solved,
@@ -660,34 +717,42 @@ class FleetControlService:
         count lands on ``SolveRequest.n_unhealthy`` and the response;
         a fully healthy problem takes this path untouched (bitwise).
         """
-        now = time.perf_counter() if now is None else now
-        cfg = self.config
-        n_unhealthy = 0
-        if cfg.sanitize:
-            # host-side health check first: the all-healthy hot path
-            # never allocates a sanitised copy
-            health = problem.health_mask(xp=np)
-            if not health.all():
-                n_unhealthy = int(health.size) - int(health.sum())
-                problem, _ = problem.sanitize(health=jnp.asarray(health))
-        fkey = quantized_problem_key(problem, cfg.quant_decimals) \
-            if cfg.warm_start else None
-        if priority is None:
-            last = self._cell_fkey.get(cell_id) if fkey is not None else None
-            priority = last is not None and last != fkey
-        if deadline_s is None:
-            deadline_s = cfg.default_deadline_s
-        self._seq += 1
-        req = SolveRequest(
-            cell_id=cell_id, problem=problem, t_submit=now,
-            t_deadline=_INF if deadline_s is None else now + deadline_s,
-            priority=bool(priority), fkey=fkey,
-            ckey=_compat_key(problem), seq=self._seq,
-            n_unhealthy=n_unhealthy)
-        self.stats.n_requests += 1
-        self.stats.n_priority += bool(req.priority)
-        (self._prio if req.priority else self._queue).append(req)
-        return req
+        trace = TraceAnnotation.is_enabled()
+        with _span(trace, SPAN_SUBMIT) as span:
+            now = time.perf_counter() if now is None else now
+            cfg = self.config
+            n_unhealthy = 0
+            if cfg.sanitize:
+                # host-side health check first: the all-healthy hot path
+                # never allocates a sanitised copy
+                health = problem.health_mask(xp=np)
+                if not health.all():
+                    n_unhealthy = int(health.size) - int(health.sum())
+                    problem, _ = problem.sanitize(health=jnp.asarray(health))
+            fkey = None
+            if cfg.warm_start:
+                with _span(trace, SPAN_KEY):
+                    fkey = quantized_problem_key(problem, cfg.quant_decimals)
+            if priority is None:
+                last = self._cell_fkey.get(cell_id) if fkey is not None \
+                    else None
+                priority = last is not None and last != fkey
+            if deadline_s is None:
+                deadline_s = cfg.default_deadline_s
+            self._seq += 1
+            req = SolveRequest(
+                cell_id=cell_id, problem=problem, t_submit=now,
+                t_deadline=_INF if deadline_s is None else now + deadline_s,
+                priority=bool(priority), fkey=fkey,
+                ckey=_compat_key(problem), seq=self._seq,
+                n_unhealthy=n_unhealthy)
+            if trace:
+                span.set_metadata(seq=req.seq, cell=cell_id,
+                                  lane=_lane(req.priority))
+            self.stats.n_requests += 1
+            self.stats.n_priority += bool(req.priority)
+            (self._prio if req.priority else self._queue).append(req)
+            return req
 
     @property
     def pending(self) -> int:
@@ -745,7 +810,8 @@ class FleetControlService:
                                         self.config)
             if reason is not None:
                 return self._serve(self._take_micro_batch(lane), reason,
-                                   priority_lane=is_prio, now=now)
+                                   priority_lane=is_prio, t_close=t,
+                                   now=now)
         return []
 
     def step(self, now: Optional[float] = None) -> list[SolveResponse]:
@@ -757,8 +823,9 @@ class FleetControlService:
         reqs = self._take_micro_batch(lane)
         if not reqs:
             return []
+        t_close = time.perf_counter() if now is None else now
         return self._serve(reqs, CLOSE_FORCED, priority_lane=is_prio,
-                           now=now)
+                           t_close=t_close, now=now)
 
     def run(self, requests=None) -> list[SolveResponse]:
         """Submit ``requests`` (``(cell_id, problem)`` pairs, optional)
@@ -880,48 +947,72 @@ class FleetControlService:
             return seed, False
         return None, False
 
+    def _batch_span(self, trace: bool, reqs: list[SolveRequest],
+                    reason: str, bucket: int, priority_lane: bool):
+        """The batch's :data:`SPAN_SERVE` span and its metadata when
+        ``trace``, else a no-op.  ``batch`` is the batch's index in
+        ``stats`` (its ``batches`` count before it); ``seqs`` lists the
+        answered requests, each of which has a ``submit`` span of that
+        ``seq``."""
+        if not trace:
+            return _NO_SPAN
+        return TraceAnnotation(
+            SPAN_SERVE, batch=self.stats.n_batches, size=len(reqs),
+            bucket=bucket, reason=reason, lane=_lane(priority_lane),
+            seqs=" ".join(str(r.seq) for r in reqs))
+
     def _shed(self, reqs: list[SolveRequest], reason: str, bucket: int, *,
-              priority_lane: bool,
+              priority_lane: bool, t_close: float,
               now: Optional[float] = None) -> list[SolveResponse]:
         """Degraded service while the bucket's circuit breaker is open:
         answer from the per-cell cache where a shape-matched solution
         exists, zeros (total self-deselection) otherwise — never a solve.
         Every response carries ``shed=True`` and ``converged=False``; the
         drain loops keep their liveness (requests always complete)."""
-        t_done = time.perf_counter() if now is None else now
-        responses = []
-        for req in reqs:
-            n = req.problem.n_devices
-            shape = (n,) if req.problem.fading is None \
-                else (n, req.problem.fading.shape[1])
-            seed = self._cell_cache.get(req.cell_id)
-            cached = seed is not None and seed.a.shape == shape
-            a = np.asarray(seed.a) if cached else np.zeros(shape, np.float32)
-            p = np.asarray(seed.power) if cached \
-                else np.zeros(shape, np.float32)
-            inst = JointSolution(
-                a=jnp.asarray(a), power=jnp.asarray(p),
-                objective=jnp.float32(0.0), n_iters=jnp.int32(0),
-                converged=jnp.asarray(False), inner_iters=jnp.int32(0))
-            responses.append(SolveResponse(
-                cell_id=req.cell_id, solution=inst, warm_started=cached,
-                cache_hit=False, latency_s=t_done - req.t_submit,
-                deadline_missed=t_done > req.t_deadline, seq=req.seq,
-                converged=False, n_iters=0, n_unhealthy=req.n_unhealthy,
-                retried=False, shed=True))
-        if self.config.record_batches:
-            self.batch_log.append(BatchRecord(
-                seqs=tuple(r.seq for r in reqs),
-                cell_ids=tuple(r.cell_id for r in reqs),
-                n_bucket=bucket, reason=reason, priority=priority_lane))
-        self.stats.record_batch(responses, 0.0, 0, 0, reason=reason,
-                                preempted=False)
-        return responses
+        trace = TraceAnnotation.is_enabled()
+        with self._batch_span(trace, reqs, reason, bucket, priority_lane):
+            t_done = time.perf_counter() if now is None else now
+            responses = []
+            for req in reqs:
+                n = req.problem.n_devices
+                shape = (n,) if req.problem.fading is None \
+                    else (n, req.problem.fading.shape[1])
+                seed = self._cell_cache.get(req.cell_id)
+                cached = seed is not None and seed.a.shape == shape
+                a = np.asarray(seed.a) if cached \
+                    else np.zeros(shape, np.float32)
+                p = np.asarray(seed.power) if cached \
+                    else np.zeros(shape, np.float32)
+                inst = JointSolution(
+                    a=jnp.asarray(a), power=jnp.asarray(p),
+                    objective=jnp.float32(0.0), n_iters=jnp.int32(0),
+                    converged=jnp.asarray(False), inner_iters=jnp.int32(0))
+                responses.append(SolveResponse(
+                    cell_id=req.cell_id, solution=inst, warm_started=cached,
+                    cache_hit=False, latency_s=t_done - req.t_submit,
+                    deadline_missed=t_done > req.t_deadline, seq=req.seq,
+                    converged=False, n_iters=0, n_unhealthy=req.n_unhealthy,
+                    retried=False, shed=True))
+            if self.config.record_batches:
+                self.batch_log.append(BatchRecord(
+                    seqs=tuple(r.seq for r in reqs),
+                    cell_ids=tuple(r.cell_id for r in reqs),
+                    n_bucket=bucket, reason=reason, priority=priority_lane))
+            self.stats.record_batch(
+                responses, 0.0, 0, 0, reason=reason, preempted=False,
+                queue_wait_us=_queue_wait_us(reqs, t_close))
+            return responses
 
     def _serve(self, reqs: list[SolveRequest], reason: str, *,
-               priority_lane: bool,
+               priority_lane: bool, t_close: float,
                now: Optional[float] = None) -> list[SolveResponse]:
-        """Pack one micro-batch, warm-start, solve, account."""
+        """Pack one micro-batch, warm-start, solve, account.
+
+        ``t_close`` is the clock reading at which ``poll``/``step``
+        closed the batch (the queue-wait stamp); ``now`` pins the
+        completion stamp on a virtual clock.  While a profiler trace
+        runs, the batch's ``serve`` span holds five children that tile
+        it: ``pack``, ``seed``, ``solve``, ``readback``, ``respond``."""
         cfg = self.config
         virtual = now is not None
         # a priority batch preempts whenever normal traffic is left waiting
@@ -933,111 +1024,130 @@ class FleetControlService:
         if self._breaker_open.get(bucket, 0) > 0:
             self._breaker_open[bucket] -= 1
             return self._shed(reqs, reason, bucket,
-                              priority_lane=priority_lane, now=now)
-        t0 = time.perf_counter()
+                              priority_lane=priority_lane, t_close=t_close,
+                              now=now)
+        trace = TraceAnnotation.is_enabled()
+        with self._batch_span(trace, reqs, reason, bucket, priority_lane):
+            t0 = time.perf_counter()
+            with _span(trace, SPAN_PACK):
+                batch = stack_problems([r.problem for r in reqs])
+                batch = pad_batch(batch, batch_size=cfg.max_batch,
+                                  n_max=bucket)
+                sizes = [r.problem.n_devices for r in reqs]
 
-        batch = stack_problems([r.problem for r in reqs])
-        batch = pad_batch(batch, batch_size=cfg.max_batch, n_max=bucket)
-        sizes = [r.problem.n_devices for r in reqs]
+            # per-request warm seeds, packed to the padded slot shape
+            # (zero rows = "no previous state" = cold,
+            # element_warm_lambda's fallback)
+            with _span(trace, SPAN_SEED):
+                sol_shape = self._sol_shape(batch)
+                per_round = (len(sol_shape) == 3)
+                init = None
+                warm_flags = [False] * len(reqs)
+                hit_flags = [False] * len(reqs)
+                if cfg.warm_start:
+                    a0 = np.zeros(sol_shape, np.float32)
+                    p0 = np.zeros(sol_shape, np.float32)
+                    for i, req in enumerate(reqs):
+                        shape = (sizes[i], sol_shape[-1]) if per_round \
+                            else (sizes[i],)
+                        seed, hit = self._lookup_seed(req.cell_id, req.fkey,
+                                                      shape)
+                        if seed is None:
+                            continue
+                        warm_flags[i], hit_flags[i] = True, hit
+                        a0[i, :shape[0]] = seed.a
+                        p0[i, :shape[0]] = seed.power
+                    if any(warm_flags):
+                        init = WarmStart(a=jnp.asarray(a0),
+                                         power=jnp.asarray(p0))
 
-        # per-request warm seeds, packed to the padded slot shape (zero
-        # rows = "no previous state" = cold, element_warm_lambda's
-        # fallback)
-        sol_shape = self._sol_shape(batch)
-        per_round = (len(sol_shape) == 3)
-        init = None
-        warm_flags = [False] * len(reqs)
-        hit_flags = [False] * len(reqs)
-        if cfg.warm_start:
-            a0 = np.zeros(sol_shape, np.float32)
-            p0 = np.zeros(sol_shape, np.float32)
-            for i, req in enumerate(reqs):
-                shape = (sizes[i], sol_shape[-1]) if per_round \
-                    else (sizes[i],)
-                seed, hit = self._lookup_seed(req.cell_id, req.fkey, shape)
-                if seed is None:
-                    continue
-                warm_flags[i], hit_flags[i] = True, hit
-                a0[i, :shape[0]] = seed.a
-                p0[i, :shape[0]] = seed.power
-            if any(warm_flags):
-                init = WarmStart(a=jnp.asarray(a0), power=jnp.asarray(p0))
+            retried = False
+            with _span(trace, SPAN_SOLVE):
+                sol = self._solve(batch, init=init)
+                jax.block_until_ready(sol.a)
+            with _span(trace, SPAN_READBACK):
+                conv_real = np.asarray(sol.converged)[:len(reqs)]
 
-        sol = self._solve(batch, init=init)
-        jax.block_until_ready(sol.a)
+            # graceful degradation: an unconverged batch gets ONE retry
+            # through the reference path (alternating + Dinkelbach) with
+            # a larger iteration budget; its result is taken wholesale.
+            # The fast path stays bitwise untouched for converged batches.
+            if cfg.retry_unconverged and not conv_real.all():
+                retried = True
+                with _span(trace, SPAN_SOLVE):
+                    sol = solve_joint_batch(batch, method="alternating",
+                                            power_solver="dinkelbach",
+                                            eps=cfg.eps,
+                                            max_iters=cfg.retry_max_iters,
+                                            init=init)
+                    jax.block_until_ready(sol.a)
+                with _span(trace, SPAN_READBACK):
+                    conv_real = np.asarray(sol.converged)[:len(reqs)]
 
-        # graceful degradation: an unconverged batch gets ONE retry
-        # through the reference path (alternating + Dinkelbach) with a
-        # larger iteration budget; its result is taken wholesale.  The
-        # fast path stays bitwise untouched for converged batches.
-        retried = False
-        conv_real = np.asarray(sol.converged)[:len(reqs)]
-        if cfg.retry_unconverged and not conv_real.all():
-            retried = True
-            sol = solve_joint_batch(batch, method="alternating",
-                                    power_solver="dinkelbach",
-                                    eps=cfg.eps,
-                                    max_iters=cfg.retry_max_iters,
-                                    init=init)
-            jax.block_until_ready(sol.a)
-            conv_real = np.asarray(sol.converged)[:len(reqs)]
+            # per-bucket circuit breaker: consecutive still-unconverged
+            # batches accumulate exponential backoff (accounted, never
+            # slept — determinism) and eventually open the breaker
+            if conv_real.all():
+                self._fail_streak[bucket] = 0
+            else:
+                streak = self._fail_streak.get(bucket, 0) + 1
+                self._fail_streak[bucket] = streak
+                self.stats.retry_backoff_s += \
+                    cfg.retry_backoff_s * (2.0 ** (min(streak, 24) - 1))
+                if streak >= cfg.breaker_threshold:
+                    self._breaker_open[bucket] = cfg.breaker_cooldown
+                    self.stats.breaker_opens += 1
 
-        # per-bucket circuit breaker: consecutive still-unconverged
-        # batches accumulate exponential backoff (accounted, never
-        # slept — determinism) and eventually open the breaker
-        if conv_real.all():
-            self._fail_streak[bucket] = 0
-        else:
-            streak = self._fail_streak.get(bucket, 0) + 1
-            self._fail_streak[bucket] = streak
-            self.stats.retry_backoff_s += \
-                cfg.retry_backoff_s * (2.0 ** (min(streak, 24) - 1))
-            if streak >= cfg.breaker_threshold:
-                self._breaker_open[bucket] = cfg.breaker_cooldown
-                self.stats.breaker_opens += 1
+            t1 = time.perf_counter()
+            self._cost.observe(bucket, t1 - t0)
+            self.buckets_used.add(bucket)
+            t_done = now if virtual else t1
 
-        t1 = time.perf_counter()
-        self._cost.observe(bucket, t1 - t0)
-        self.buckets_used.add(bucket)
-        t_done = now if virtual else t1
+            # one transfer per field for the whole batch, then numpy
+            # slicing
+            with _span(trace, SPAN_READBACK):
+                a_np = np.asarray(sol.a)
+                p_np = np.asarray(sol.power)
+                obj_np = np.asarray(sol.objective)
+                conv_np = np.asarray(sol.converged)
+                outer_np = np.asarray(sol.n_iters)
+                inner_np = np.asarray(sol.inner_iters)
 
-        # one transfer per field for the whole batch, then numpy slicing
-        a_np = np.asarray(sol.a)
-        p_np = np.asarray(sol.power)
-        obj_np = np.asarray(sol.objective)
-        conv_np = np.asarray(sol.converged)
-        outer_np = np.asarray(sol.n_iters)
-        inner_np = np.asarray(sol.inner_iters)
-
-        responses = []
-        outer = int(np.max(outer_np))
-        inner = int(np.sum(inner_np))
-        for i, req in enumerate(reqs):
-            n = sizes[i]
-            inst = JointSolution(
-                a=a_np[i, :n], power=p_np[i, :n], objective=obj_np[i],
-                n_iters=outer_np[i] if outer_np.ndim else outer_np,
-                converged=conv_np[i],
-                inner_iters=inner_np[i] if inner_np.ndim else inner_np)
-            if cfg.warm_start:
-                state = inst.resume
-                self._feature_cache.put(req.fkey, state)
-                self._cell_cache.put(req.cell_id, state)
-                self._cell_fkey.put(req.cell_id, req.fkey)
-            responses.append(SolveResponse(
-                cell_id=req.cell_id, solution=inst,
-                warm_started=warm_flags[i], cache_hit=hit_flags[i],
-                latency_s=t_done - req.t_submit,
-                deadline_missed=t_done > req.t_deadline, seq=req.seq,
-                converged=bool(conv_np[i]),
-                n_iters=int(outer_np[i] if outer_np.ndim else outer_np),
-                n_unhealthy=req.n_unhealthy, retried=retried))
-        if cfg.record_batches:
-            self.batch_log.append(BatchRecord(
-                seqs=tuple(r.seq for r in reqs),
-                cell_ids=tuple(r.cell_id for r in reqs),
-                n_bucket=bucket, reason=reason, priority=priority_lane))
-        self.stats.record_batch(responses, t1 - t0, outer, inner,
-                                reason=reason, preempted=preempted,
-                                retried=retried)
-        return responses
+            with _span(trace, SPAN_RESPOND):
+                responses = []
+                outer = int(np.max(outer_np))
+                inner = int(np.sum(inner_np))
+                for i, req in enumerate(reqs):
+                    n = sizes[i]
+                    inst = JointSolution(
+                        a=a_np[i, :n], power=p_np[i, :n],
+                        objective=obj_np[i],
+                        n_iters=outer_np[i] if outer_np.ndim else outer_np,
+                        converged=conv_np[i],
+                        inner_iters=inner_np[i] if inner_np.ndim
+                        else inner_np)
+                    if cfg.warm_start:
+                        state = inst.resume
+                        self._feature_cache.put(req.fkey, state)
+                        self._cell_cache.put(req.cell_id, state)
+                        self._cell_fkey.put(req.cell_id, req.fkey)
+                    responses.append(SolveResponse(
+                        cell_id=req.cell_id, solution=inst,
+                        warm_started=warm_flags[i], cache_hit=hit_flags[i],
+                        latency_s=t_done - req.t_submit,
+                        deadline_missed=t_done > req.t_deadline,
+                        seq=req.seq, converged=bool(conv_np[i]),
+                        n_iters=int(outer_np[i] if outer_np.ndim
+                                    else outer_np),
+                        n_unhealthy=req.n_unhealthy, retried=retried))
+                if cfg.record_batches:
+                    self.batch_log.append(BatchRecord(
+                        seqs=tuple(r.seq for r in reqs),
+                        cell_ids=tuple(r.cell_id for r in reqs),
+                        n_bucket=bucket, reason=reason,
+                        priority=priority_lane))
+                self.stats.record_batch(
+                    responses, t1 - t0, outer, inner, reason=reason,
+                    preempted=preempted, retried=retried,
+                    queue_wait_us=_queue_wait_us(reqs, t_close))
+                return responses
