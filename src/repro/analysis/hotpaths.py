@@ -142,12 +142,12 @@ def _build_solve_joint_fused() -> HotPathRun:
     doc="batched fused solve (the service's _solve payload); zero "
         "recompiles for a fixed (batch, bucket) signature")
 def _build_solve_joint_batch() -> HotPathRun:
-    from repro.core.batch import pad_batch, solve_joint_batch, stack_problems
+    from repro.core.batch import solve_joint_batch, stack_problems
     from repro.core.problem import sample_problem
 
     def batch(seed0: int):
         probs = [sample_problem(seed0 + i, 16 + 4 * i) for i in range(3)]
-        return pad_batch(stack_problems(probs), batch_size=4, n_max=32)
+        return stack_problems(probs, batch_size=4, n_max=32)
 
     batch_a, batch_b = batch(0), batch(10)
 

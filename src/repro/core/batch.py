@@ -137,16 +137,25 @@ class ProblemBatch:
         return out
 
 
-def _pad_tail(x: jax.Array, n_max: int, fill: float) -> np.ndarray:
-    # numpy, not jnp: stacking happens on the serving hot path (one
-    # micro-batch per step), where B x n_fields eager jnp pad/stack ops
-    # cost ~100x their numpy equivalents in dispatch overhead alone
-    x = np.asarray(x)
-    pad = [(0, n_max - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
-    return np.pad(x, pad, constant_values=fill)
+def _slot_array(leaves: list[np.ndarray], shape: tuple[int, int],
+                fill: float, name: str) -> np.ndarray:
+    # one host array at the final [B, N_max, ...] slot shape, pre-filled
+    # with the leaf's neutral fill, each instance written into its rows;
+    # the dtype is what jnp.asarray(np.stack(leaves)) would give
+    trail = leaves[0].shape[1:]
+    if any(x.shape[1:] != trail for x in leaves):
+        raise ValueError(f"{name} trailing shape differs across the batch "
+                         f"({sorted({x.shape[1:] for x in leaves})})")
+    dtype = jax.dtypes.canonicalize_dtype(np.result_type(*leaves))
+    out = np.full(shape + trail, fill, dtype)
+    for b, x in enumerate(leaves):
+        out[b, :x.shape[0]] = x
+    return out
 
 
-def stack_problems(problems: Sequence[WirelessFLProblem]) -> ProblemBatch:
+def stack_problems(problems: Sequence[WirelessFLProblem], *,
+                   batch_size: Optional[int] = None,
+                   n_max: Optional[int] = None) -> ProblemBatch:
     """Stack instances into a ProblemBatch, padding ragged fleet sizes.
 
     All instances must share the static metadata (``p_max``, ``tau_th``,
@@ -158,6 +167,13 @@ def stack_problems(problems: Sequence[WirelessFLProblem]) -> ProblemBatch:
     non-fading instances' objective (summed over K synthetic rounds).
     Pass explicit unit fading to opt a static-channel instance into a
     fading batch.
+
+    ``batch_size`` / ``n_max`` pad to fixed slot shapes exactly as
+    :func:`pad_batch` does (default: the natural ``(len(problems),
+    max N)``), but in one host pass: every leaf is written once into a
+    numpy array of the final shape and the whole batch goes to the
+    device in a single ``jax.device_put``.  Host (numpy) leaves are
+    never read back from the device.
     """
     if not problems:
         raise ValueError("stack_problems needs at least one problem")
@@ -170,7 +186,8 @@ def stack_problems(problems: Sequence[WirelessFLProblem]) -> ProblemBatch:
                     f"({getattr(p, f)} vs {getattr(ref, f)}); solve instances "
                     "with differing statics in separate batches")
 
-    n_max = max(p.n_devices for p in problems)
+    b0 = len(problems)
+    n0 = max(p.n_devices for p in problems)
     n_fading = sum(p.fading is not None for p in problems)
     if 0 < n_fading < len(problems):
         raise ValueError(
@@ -196,34 +213,28 @@ def stack_problems(problems: Sequence[WirelessFLProblem]) -> ProblemBatch:
         raise ValueError("bits rank ([N] vs [N, K]) must be uniform "
                          "across the batch")
 
-    stacked: dict[str, jax.Array] = {}
-    for name, fill in _PAD_VALUES.items():
-        stacked[name] = jnp.asarray(np.stack(
-            [_pad_tail(getattr(p, name), n_max, fill) for p in problems]))
-    fading = None
-    if n_fading:
-        fading = jnp.asarray(np.stack(
-            [_pad_tail(p.fading, n_max, 1.0) for p in problems]))
-    interference = None
-    if n_interf:
-        interference = jnp.asarray(np.stack(
-            [_pad_tail(p.interference, n_max, 0.0) for p in problems]))
-    bits = None
-    if n_bits:
-        bits = jnp.asarray(np.stack(
-            [_pad_tail(p.bits, n_max, 32.0) for p in problems]))
+    bsz = b0 if batch_size is None else batch_size
+    nmx = n0 if n_max is None else n_max
+    if bsz < b0 or nmx < n0:
+        raise ValueError(f"stack_problems cannot shrink ({b0}, {n0}) -> "
+                         f"({bsz}, {nmx})")
 
-    sizes = np.array([p.n_devices for p in problems], np.int32)
-    mask = jnp.asarray(np.arange(n_max)[None, :] < sizes[:, None])
+    def slots(name: str, fill: float) -> np.ndarray:
+        return _slot_array([np.asarray(getattr(p, name)) for p in problems],
+                           (bsz, nmx), fill, name)
+
+    leaves = {name: slots(name, fill) for name, fill in _PAD_VALUES.items()}
+    for name, fill, present in (("fading", 1.0, n_fading),
+                                ("interference", 0.0, n_interf),
+                                ("bits", 32.0, n_bits)):
+        leaves[name] = slots(name, fill) if present else None
+    sizes = np.zeros(bsz, np.int32)
+    sizes[:b0] = [p.n_devices for p in problems]
+    mask = np.arange(nmx)[None, :] < sizes[:, None]
     prob = WirelessFLProblem(
-        fading=fading,
-        interference=interference,
-        bits=bits,
-        **stacked,
-        **{f: getattr(ref, f) for f in _STATIC_FIELDS},
-    )
-    return ProblemBatch(problem=prob, mask=mask,
-                        fleet_sizes=jnp.asarray(sizes))
+        **leaves, **{f: getattr(ref, f) for f in _STATIC_FIELDS})
+    return jax.device_put(ProblemBatch(problem=prob, mask=mask,
+                                       fleet_sizes=sizes))
 
 
 def pad_batch(batch: ProblemBatch, *, batch_size: Optional[int] = None,

@@ -33,8 +33,9 @@ recomputation the warm-start path can skip.
 * **micro-batching** — queued requests with compatible static metadata
   are packed into a padded :class:`~repro.core.batch.ProblemBatch` of
   fixed slot shape (``max_batch`` instance slots, device axis padded to
-  a power-of-two bucket via :func:`repro.core.batch.pad_batch`), so jit
-  compiles one program per bucket instead of one per request shape;
+  a power-of-two bucket; :func:`repro.core.batch.stack_problems` builds
+  it in one host pass and one upload), so jit compiles one program per
+  bucket instead of one per request shape;
 * **warm starts** — each solved request's ``(a*, P*)`` is cached and fed
   back as ``init`` for the cell's next solve (bit-identical solutions,
   collapsed inner iterations — see ``core.alternating``'s warm-start
@@ -83,7 +84,6 @@ from repro.core.alternating import JointSolution, WarmStart
 from repro.core.batch import (
     _PAD_VALUES,
     _STATIC_FIELDS,
-    pad_batch,
     solve_joint_batch,
     stack_problems,
 )
@@ -681,8 +681,8 @@ class FleetControlService:
         b = _next_pow2(1, cfg.min_device_bucket)
         while b <= hi:
             prob = _resize_problem(template, b)
-            batch = pad_batch(stack_problems([prob]),
-                              batch_size=cfg.max_batch, n_max=b)
+            batch = stack_problems([prob], batch_size=cfg.max_batch,
+                                   n_max=b)
             t0 = time.perf_counter()
             jax.block_until_ready(self._solve(batch, init=None).a)
             if warm:
@@ -1030,9 +1030,9 @@ class FleetControlService:
         with self._batch_span(trace, reqs, reason, bucket, priority_lane):
             t0 = time.perf_counter()
             with _span(trace, SPAN_PACK):
-                batch = stack_problems([r.problem for r in reqs])
-                batch = pad_batch(batch, batch_size=cfg.max_batch,
-                                  n_max=bucket)
+                batch = stack_problems([r.problem for r in reqs],
+                                       batch_size=cfg.max_batch,
+                                       n_max=bucket)
                 sizes = [r.problem.n_devices for r in reqs]
 
             # per-request warm seeds, packed to the padded slot shape

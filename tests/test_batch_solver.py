@@ -14,6 +14,7 @@ from repro.core import (
     make_batch,
     make_mixed_batch,
     make_problem,
+    pad_batch,
     sample_problem,
     solve_joint,
     solve_joint_batch,
@@ -72,6 +73,63 @@ class TestStacking:
         batch = stack_problems([a, c])
         assert batch.problem.fading.shape == (2, 8, 3)
         np.testing.assert_allclose(np.asarray(batch.problem.fading[1]), 1.0)
+
+    @pytest.mark.parametrize("case, slots", [
+        ("static", (4, 16)),          # ragged, padding on both axes
+        ("static", (3, 16)),          # padding on the device axis only
+        ("static", (5, 12)),          # padding on the batch axis only
+        ("static", (None, None)),     # no padding at all
+        ("fading", (4, 16)),
+        ("interference_n", (4, 16)),
+        ("interference_nk", (4, 16)),
+        ("bits", (4, 16)),
+        ("host_f64", (4, 16)),        # numpy float64 leaves, as clients send
+    ])
+    def test_slot_shape_matches_pad_batch(self, case, slots):
+        """Packing at slot shape in one pass is ``pad_batch`` of the
+        natural stack, bit for bit, in dtype, shape and weak type."""
+        sizes = [5, 12, 9]
+        fading = case in ("fading", "interference_nk")
+        probs = [sample_problem(i, n, with_fading=fading, n_rounds=3)
+                 for i, n in enumerate(sizes)]
+        rng = np.random.default_rng(0)
+        if case == "interference_n":
+            probs = [dataclasses.replace(p, interference=jnp.asarray(
+                rng.uniform(0, 1e-12, n), jnp.float32))
+                for p, n in zip(probs, sizes)]
+        elif case == "interference_nk":
+            probs = [dataclasses.replace(p, interference=jnp.asarray(
+                rng.uniform(0, 1e-12, (n, 3)), jnp.float32))
+                for p, n in zip(probs, sizes)]
+        elif case == "bits":
+            probs = [dataclasses.replace(p, bits=jnp.asarray(
+                rng.choice([8.0, 16.0, 32.0], n), jnp.float32))
+                for p, n in zip(probs, sizes)]
+        elif case == "host_f64":
+            probs = [dataclasses.replace(p, **{
+                f: np.asarray(getattr(p, f), np.float64)
+                for f in ("distance_m", "bandwidth_hz", "energy_budget_j",
+                          "weights")}, fading=rng.uniform(0.1, 2.0, (n, 1)),
+                n_rounds=1) for p, n in zip(probs, sizes)]
+        bsz, nmx = slots
+        got = stack_problems(probs, batch_size=bsz, n_max=nmx)
+        want = stack_problems(probs)
+        if slots != (None, None):
+            want = pad_batch(want, batch_size=bsz, n_max=nmx)
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert isinstance(g, jax.Array)
+            assert (g.dtype, g.shape, g.weak_type) == \
+                (w.dtype, w.shape, w.weak_type)
+            assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
+        assert got.batch_size == (bsz or 3) and got.n_max == (nmx or 12)
+
+    def test_slot_shape_cannot_shrink(self):
+        probs = [sample_problem(i, n) for i, n in enumerate([5, 12])]
+        with pytest.raises(ValueError, match="cannot shrink"):
+            stack_problems(probs, batch_size=1)
+        with pytest.raises(ValueError, match="cannot shrink"):
+            stack_problems(probs, n_max=8)
 
 
 class TestBatchAgreement:

@@ -5,6 +5,8 @@ the open-loop control plane: the batch-close policy, deadline stamping
 and miss accounting, priority-lane preemption, `_next_pow2` /
 `latency_percentile` edge semantics, and the AOT warmup guarantee (the
 first post-warmup request pays no trace spike)."""
+import contextlib
+import dataclasses
 import math
 
 import numpy as np
@@ -65,6 +67,53 @@ class TestServiceCorrectness:
             ref = solve_joint_fused(probs[r.cell_id])
             np.testing.assert_allclose(np.asarray(r.solution.a),
                                        np.asarray(ref.a), atol=1e-6)
+
+    def test_pack_is_one_upload_and_no_readback(self, monkeypatch):
+        """A micro-batch of host (numpy) requests is packed with exactly
+        one ``jax.device_put`` and no device array is read back inside
+        the ``pack`` span."""
+        import jax
+
+        from repro.serve import fleet_service
+
+        rng = np.random.default_rng(0)
+        probs = [sample_problem(i, n) for i, n in enumerate([5, 12, 9])]
+        probs = [dataclasses.replace(
+            p, fading=rng.uniform(0.1, 2.0, (p.n_devices, 1)), n_rounds=1,
+            **{f: np.asarray(getattr(p, f)) for f in fleet_service._PAD_VALUES})
+            for p in probs]
+        in_pack, puts, reads = [False], [], []
+        real_put, real_asarray, real_span = (jax.device_put, np.asarray,
+                                             fleet_service._span)
+
+        def counting_put(*args, **kwargs):
+            if in_pack[0]:
+                puts.append(args[0])
+            return real_put(*args, **kwargs)
+
+        def guarded_asarray(a, *args, **kwargs):
+            if in_pack[0] and isinstance(a, jax.Array):
+                reads.append(a.shape)
+            return real_asarray(a, *args, **kwargs)
+
+        @contextlib.contextmanager
+        def watched(on, name):
+            with real_span(on, name):
+                in_pack[0] = name == fleet_service.SPAN_PACK
+                try:
+                    yield
+                finally:
+                    in_pack[0] = False
+
+        monkeypatch.setattr(jax, "device_put", counting_put)
+        monkeypatch.setattr(np, "asarray", guarded_asarray)
+        monkeypatch.setattr(fleet_service, "_span", watched)
+        svc = FleetControlService(ServiceConfig(max_batch=4))
+        responses = svc.run(list(enumerate(probs)))
+        assert len(responses) == 3 and svc.stats.n_batches == 1
+        assert len(puts) == 1 and reads == []
+        batch = puts[0]
+        assert batch.batch_size == 4 and batch.n_max == 16
 
     def test_incompatible_statics_split_batches(self):
         a = sample_problem(0, 8, tau_th=0.08)
