@@ -27,6 +27,7 @@ from repro.core.multicell import (
     MultiCellSolution,
     cell_interference,
     grid_coupling,
+    hex_coupling,
     make_multicell,
     solve_coupled,
     solve_coupled_loop,
@@ -71,7 +72,7 @@ __all__ = [
     "solve_joint_fused", "FleetElements", "problem_elements",
     "fused_fixed_point", "fused_fixed_point_flat",
     "MultiCellProblem", "MultiCellSolution", "CoupledDuals",
-    "make_multicell", "grid_coupling", "cell_interference",
+    "make_multicell", "grid_coupling", "hex_coupling", "cell_interference",
     "solve_coupled", "solve_coupled_loop",
     "ParticipationDraw", "SchedulerState",
     "ProbabilisticScheduler", "DeterministicScheduler", "UniformScheduler",

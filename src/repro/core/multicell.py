@@ -46,9 +46,23 @@ tick through this loop and warm-starts ``(I, mu)`` (and the element warm
 start) from the previous tick via :class:`CoupledDuals` /
 ``MultiCellSolution.resume`` — on a coherent channel the outer loop then
 collapses to one or two iterations (``multicell_solver`` benchmarks).
+
+Layouts: :func:`grid_coupling` (a square grid, ``gain / dist^alpha``)
+and :func:`hex_coupling` (3GPP TR 38.901 UMa: a hexagonal lattice of
+three-sector sites, NLOS path loss and the sector antenna pattern).
+
+Profiler spans: while a ``jax.profiler`` trace is being taken, each
+outer iteration writes ``multicell.solve`` (the union solve, to
+completion), ``multicell.readback`` (``a``/``P`` to the host),
+``multicell.price`` (the knapsack) and ``multicell.interference``
+(``I`` from the answers, the residual, and the next iteration's
+interference leaf built and uploaded; the first iteration's leaf is
+built in one more such span before the loop).  With no trace running
+the loop pays one ``is_enabled`` check and builds no annotation.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import NamedTuple, Optional, Sequence
@@ -56,6 +70,7 @@ from typing import NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.alternating import WarmStart, solve_joint_fused
 from repro.core.batch import (
@@ -66,6 +81,20 @@ from repro.core.batch import (
     stack_problems,
 )
 from repro.core.problem import WirelessFLProblem
+
+# profiler spans, written only while a jax.profiler trace is being taken
+SPAN_SOLVE = "multicell.solve"                # union solve, to completion
+SPAN_READBACK = "multicell.readback"          # a / P device -> host
+SPAN_PRICE = "multicell.price"                # backhaul knapsack
+SPAN_INTERFERENCE = "multicell.interference"  # I update, next leaf upload
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _span(on: bool, name: str):
+    """Profiler span ``name`` when ``on`` (a trace is running), else a
+    shared no-op: with tracing off no annotation is built."""
+    return TraceAnnotation(name) if on else _NO_SPAN
 
 
 @jax.tree_util.register_dataclass
@@ -142,6 +171,99 @@ def grid_coupling(n_cells: int, *, gain: float, alpha: float = 2.0,
     return g
 
 
+# 3GPP TR 38.901 V17, urban macro (UMa).  Table 7.2-1: BS and UT
+# heights, the least BS-UT distance on the ground.
+UMA_BS_HEIGHT_M = 25.0
+UMA_UT_HEIGHT_M = 1.5
+UMA_MIN_DISTANCE_2D_M = 35.0
+# Table 7.4.1-1, UMa NLOS PL'_UMa-NLOS (dB; d3D in m, fc in GHz):
+# a + b log10(d3D) + c log10(fc) - e (h_UT - 1.5)
+UMA_NLOS_PL_DB = (13.54, 39.08, 20.0, 0.6)
+# Table 7.3-1, horizontal pattern A(phi) = -min(12 (phi / phi_3dB)^2, A_m)
+SECTOR_PHI_3DB_DEG = 65.0
+SECTOR_A_M_DB = 30.0
+# boresights of a site's three sectors (TR 38.901 Sec. 7.8, ITU-R M.2412)
+SECTOR_BORESIGHTS_DEG = (30.0, 150.0, 270.0)
+
+
+def uma_nlos_path_loss_db(d3d_m, fc_ghz: float) -> np.ndarray:
+    """UMa NLOS path loss PL' (dB) at 3-D distance ``d3d_m``."""
+    a, b, c, e = UMA_NLOS_PL_DB
+    return (a + b * np.log10(d3d_m) + c * np.log10(fc_ghz)
+            - e * (UMA_UT_HEIGHT_M - 1.5))
+
+
+def sector_pattern_db(phi_deg) -> np.ndarray:
+    """Horizontal sector antenna gain (dB) at ``phi_deg`` off boresight,
+    any angle (wrapped to [-180, 180))."""
+    phi = (np.asarray(phi_deg, np.float64) + 180.0) % 360.0 - 180.0
+    return -np.minimum(12.0 * (phi / SECTOR_PHI_3DB_DEG) ** 2, SECTOR_A_M_DB)
+
+
+def hex_sites(rings: int, isd_m: float) -> np.ndarray:
+    """``[1 + 3 R (R + 1), 2]`` site positions (m) of a hexagonal lattice
+    of ``R = rings`` rings around a centre site, ``isd_m`` apart."""
+    q, r = np.meshgrid(np.arange(-rings, rings + 1),
+                       np.arange(-rings, rings + 1), indexing="ij")
+    keep = np.abs(q + r) <= rings
+    q, r = q[keep], r[keep]
+    return isd_m * np.stack([q + 0.5 * r, np.sqrt(3.0) / 2.0 * r], axis=1)
+
+
+def sector_points(rings: int, isd_m: float, n_points: int,
+                  seed: int) -> np.ndarray:
+    """``[C, n_points, 2]`` points drawn uniformly in each sector.
+
+    Cell ``c = 3 s + k`` is sector ``k`` of site ``s``: the third of the
+    site's hexagon (circumradius ``isd_m / sqrt(3)``) that is the rhombus
+    spanned by the two hexagon corners 60 degrees either side of its
+    boresight."""
+    sites = hex_sites(rings, isd_m)
+    rng = np.random.default_rng(seed)
+    radius = isd_m / np.sqrt(3.0)
+    bore = np.radians(SECTOR_BORESIGHTS_DEG)
+    corners = radius * np.stack(
+        [np.stack([np.cos(bore - np.pi / 3), np.sin(bore - np.pi / 3)], -1),
+         np.stack([np.cos(bore + np.pi / 3), np.sin(bore + np.pi / 3)], -1)],
+        axis=1)                                            # [3, 2, 2]
+    st = rng.uniform(size=(len(sites), 3, n_points, 2))
+    pts = sites[:, None, None, :] + st @ corners[None]     # [S, 3, P, 2]
+    return pts.reshape(3 * len(sites), n_points, 2)
+
+
+def hex_coupling(rings: int, isd_m: float = 500.0, *, n_points: int = 64,
+                 seed: int = 0, fc_ghz: float = 3.5) -> np.ndarray:
+    """Coupling matrix of a 3GPP TR 38.901 UMa metro (W/W, zero diagonal).
+
+    ``1 + 3 R (R + 1)`` three-sector sites on a hexagonal lattice of
+    ``R = rings`` rings, ``isd_m`` apart, no wrap-around; cell ``c = 3 s +
+    k`` is sector ``k`` of site ``s`` (:func:`sector_points`).
+    ``G[c, c']`` is the mean, over ``n_points`` points drawn from ``seed``
+    uniformly in sector ``c'``, of ``10^((A_c(phi) - PL(d3D)) / 10)``:
+    the UMa NLOS path loss (:func:`uma_nlos_path_loss_db`) from the point
+    to cell c's site, with the ground distance held to at least
+    ``UMA_MIN_DISTANCE_2D_M``, and cell c's sector pattern
+    (:func:`sector_pattern_db`) at the point's bearing from its site.
+    """
+    sites = hex_sites(rings, isd_m)
+    pts = sector_points(rings, isd_m, n_points, seed)      # [C, P, 2]
+    n_cells = len(pts)
+    site_of = np.repeat(np.arange(len(sites)), 3)
+    bore = np.tile(SECTOR_BORESIGHTS_DEG, len(sites))
+    dh = UMA_BS_HEIGHT_M - UMA_UT_HEIGHT_M
+    g = np.empty((n_cells, n_cells))
+    for c in range(n_cells):          # one receiving cell's row at a time
+        rel = pts - sites[site_of[c]]                      # [C, P, 2]
+        d2d = np.maximum(np.hypot(rel[..., 0], rel[..., 1]),
+                         UMA_MIN_DISTANCE_2D_M)
+        loss = uma_nlos_path_loss_db(np.sqrt(d2d ** 2 + dh ** 2), fc_ghz)
+        phi = np.degrees(np.arctan2(rel[..., 1], rel[..., 0])) - bore[c]
+        g[c] = np.mean(10.0 ** ((sector_pattern_db(phi) - loss) / 10.0),
+                       axis=1)
+    np.fill_diagonal(g, 0.0)
+    return g
+
+
 def pad_metro(mc: MultiCellProblem, *, n_cells: Optional[int] = None,
               n_max: Optional[int] = None) -> MultiCellProblem:
     """Pad a metro to fixed ``(n_cells, n_max)`` slot shapes.
@@ -180,6 +302,9 @@ class MultiCellSolution(NamedTuple):
     outer_iters: int          # dual-decomposition iterations run
     residual: float           # final coupled-KKT residual
     converged: bool           # residual <= outer_tol within the budget
+    # the interference estimate ``batch`` was solved at ([C] or [C, K]);
+    # ``interference`` is the next estimate, recomputed from ``batch``
+    solved_at: np.ndarray
     # True when the outer loop ran out of iterations: the returned state
     # is then the *best-residual* iterate seen (best-feasible-so-far),
     # not the last step's — see docs/robustness.md
@@ -340,7 +465,9 @@ def solve_coupled(mc: MultiCellProblem,
     returned solution is the **best-residual iterate seen** with
     ``hit_iter_cap=True`` — degraded but usable, never the last
     (possibly oscillating) step by accident; converged solves are
-    bit-identical to the pre-flag behaviour.
+    bit-identical to the pre-flag behaviour.  On either path
+    ``solved_at`` is the estimate ``I`` that the returned ``batch`` was
+    solved at, and ``interference`` the one recomputed from it.
     """
     cells = mc.cells
     if damping <= 0.0 or damping > 1.0:
@@ -371,38 +498,51 @@ def solve_coupled(mc: MultiCellProblem,
     a_proj: np.ndarray | jax.Array = jnp.zeros(0)
     load = np.zeros(k_rounds) if per_round else np.float64(0.0)
     residual, converged, t = float("inf"), False, 0
-    best = None   # best-residual iterate: (residual, bs, a_proj, mu, load, I)
+    # best-residual iterate: (residual, bs, a_proj, mu, load, I, solved_at)
+    best = None
+    trace = TraceAnnotation.is_enabled()
+    with _span(trace, SPAN_INTERFERENCE):
+        problem = _with_interference(cells, interference)
     for t in range(1, outer_iters + 1):  # noqa: B007 - read after the loop
-        bs = solve_joint_batch(
-            _with_interference(cells, interference), method=method,
-            power_solver=power_solver, eps=eps, max_iters=max_iters,
-            chunk_elements=chunk_elements, mesh=mesh, shard=shard,
-            sanitize=sanitize,
-            init=warm if warm_start else None)
-        if mc.backhaul_bits is None:
-            # no projection: keep the solver's arrays untouched so the
-            # zero-coupling path stays bitwise identical to the
-            # uncoupled solve
-            a_proj = bs.a
-            mu_new = np.zeros_like(mu)
-            load = np.asarray(bs.a, np.float64).sum(axis=(0, 1)) * s_bits
-            i_src = np.asarray(bs.a, np.float64)
-        else:
-            a_proj, mu_new, load = _backhaul_project(
-                np.asarray(bs.a), w, s_bits, mc.backhaul_bits)
-            i_src = a_proj
-        i_new = cell_interference(coupling, i_src, np.asarray(bs.power))
-        residual = max(_relative_delta(interference, i_new),
-                       _relative_delta(np.atleast_1d(mu),
-                                       np.atleast_1d(mu_new)))
-        converged = residual <= outer_tol
-        if best is None or residual < best[0]:
-            best = (residual, bs, a_proj, mu_new, load, i_new)
-        interference = i_new if converged or damping >= 1.0 \
-            else interference + damping * (i_new - interference)
-        mu = mu_new
-        if warm_start:
-            warm = bs.resume
+        solved_at = interference
+        with _span(trace, SPAN_SOLVE):
+            bs = jax.block_until_ready(solve_joint_batch(
+                problem, method=method,
+                power_solver=power_solver, eps=eps, max_iters=max_iters,
+                chunk_elements=chunk_elements, mesh=mesh, shard=shard,
+                sanitize=sanitize,
+                init=warm if warm_start else None))
+        with _span(trace, SPAN_READBACK):
+            a_host = np.asarray(bs.a)
+            p_host = np.asarray(bs.power)
+        with _span(trace, SPAN_PRICE):
+            if mc.backhaul_bits is None:
+                # no projection: keep the solver's arrays untouched so
+                # the zero-coupling path stays bitwise identical to the
+                # uncoupled solve
+                a_proj = bs.a
+                mu_new = np.zeros_like(mu)
+                i_src = np.asarray(a_host, np.float64)
+                load = i_src.sum(axis=(0, 1)) * s_bits
+            else:
+                a_proj, mu_new, load = _backhaul_project(
+                    a_host, w, s_bits, mc.backhaul_bits)
+                i_src = a_proj
+        with _span(trace, SPAN_INTERFERENCE):
+            i_new = cell_interference(coupling, i_src, p_host)
+            residual = max(_relative_delta(interference, i_new),
+                           _relative_delta(np.atleast_1d(mu),
+                                           np.atleast_1d(mu_new)))
+            converged = residual <= outer_tol
+            if best is None or residual < best[0]:
+                best = (residual, bs, a_proj, mu_new, load, i_new, solved_at)
+            interference = i_new if converged or damping >= 1.0 \
+                else interference + damping * (i_new - interference)
+            mu = mu_new
+            if warm_start:
+                warm = bs.resume
+            if not converged and t < outer_iters:
+                problem = _with_interference(cells, interference)
         if converged:
             break
 
@@ -410,7 +550,7 @@ def solve_coupled(mc: MultiCellProblem,
     if hit_iter_cap:
         # iteration cap: hand back the best-residual iterate seen, not
         # whatever the last (possibly oscillating) step produced
-        residual, bs, a_proj, mu, load, interference = best
+        residual, bs, a_proj, mu, load, interference, solved_at = best
 
     if mc.backhaul_bits is None:
         final = bs
@@ -424,7 +564,7 @@ def solve_coupled(mc: MultiCellProblem,
     return MultiCellSolution(batch=final, interference=interference, mu=mu,
                              backhaul_load=load, outer_iters=t,
                              residual=residual, converged=converged,
-                             hit_iter_cap=hit_iter_cap)
+                             hit_iter_cap=hit_iter_cap, solved_at=solved_at)
 
 
 @functools.lru_cache(maxsize=32)
@@ -482,6 +622,7 @@ def solve_coupled_loop(mc: MultiCellProblem,
     residual, converged, t = float("inf"), False, 0
     conv_all = True
     for t in range(1, outer_iters + 1):  # noqa: B007 - read after the loop
+        solved_at = interference
         sols = []
         for c, prob in enumerate(problems):
             i_c = interference[c]
@@ -524,4 +665,4 @@ def solve_coupled_loop(mc: MultiCellProblem,
     return MultiCellSolution(batch=batch, interference=interference, mu=mu,
                              backhaul_load=load, outer_iters=t,
                              residual=residual, converged=converged,
-                             hit_iter_cap=not converged)
+                             hit_iter_cap=not converged, solved_at=solved_at)

@@ -49,7 +49,10 @@ recomputation the warm-start path can skip.
 * **profiler spans** — while a ``jax.profiler`` trace is being taken,
   ``submit`` and every answered batch write ``fleet_service.*``
   annotations (``SPAN_*``) on the trace's host clock: a request's intake
-  and key, a batch's packing, seeding, solve, read back and responses.
+  and key, a batch's packing, seeding, solve, read back and responses;
+  a coupled metro tick and its padding (``fleet_service.metro``,
+  ``fleet_service.metro_pad``), around the outer loop's own
+  ``multicell.*`` spans.
   With no trace running each call pays one ``is_enabled`` check and
   builds no annotation (``docs/serving.md``, "Observing the service").
 
@@ -116,6 +119,8 @@ SPAN_SEED = "fleet_service.seed"          # warm seeds looked up and uploaded
 SPAN_SOLVE = "fleet_service.solve"        # the batch solve, to completion
 SPAN_READBACK = "fleet_service.readback"  # device -> host reads
 SPAN_RESPOND = "fleet_service.respond"    # responses, caches, accounting
+SPAN_METRO = "fleet_service.metro"        # one coupled metro tick
+SPAN_METRO_PAD = "fleet_service.metro_pad"  # pad_metro to the tick's bucket
 
 _NO_SPAN = contextlib.nullcontext()
 
@@ -886,39 +891,46 @@ class FleetControlService:
           collapses to one or two iterations (shape-mismatched state is
           dropped, so metro reconfigurations just run cold);
         * **accounting** — ``stats`` gains ``metro_ticks`` /
-          ``metro_outer_iters`` / ``metro_warm`` counters.
+          ``metro_outer_iters`` / ``metro_warm`` / ``metro_caps``
+          counters;
+        * **spans** — under a profiler trace, :data:`SPAN_METRO` covers
+          the tick, :data:`SPAN_METRO_PAD` its padding, and the outer
+          loop writes ``multicell.*`` spans inside it.
 
         Uses the service's configured method/power solver/warm-start
         policy; ``outer_*`` and ``damping`` are per-call because the
         coupling strength is a property of the metro, not the service.
         """
         cfg = self.config
-        t0 = time.perf_counter()
-        n_cells = metro.n_cells
-        bucket_c = _next_pow2(n_cells)
-        bucket_n = _next_pow2(metro.cells.n_max, cfg.min_device_bucket)
-        padded = pad_metro(metro, n_cells=bucket_c, n_max=bucket_n)
-        per_round = padded.cells.problem.fading is not None
-        i_shape = (bucket_c, padded.cells.problem.fading.shape[-1]) \
-            if per_round else (bucket_c,)
-        init: Optional[CoupledDuals] = \
-            self._metro_duals.get(metro_id) if cfg.warm_start else None
-        if init is not None and np.shape(init.interference) != i_shape:
-            init = None               # metro resized: run cold
-        sol = solve_coupled_core(
-            padded, outer_iters=outer_iters, outer_tol=outer_tol,
-            damping=damping, method=cfg.method,
-            power_solver=cfg.power_solver, eps=cfg.eps,
-            max_iters=cfg.max_iters, warm_start=cfg.warm_start, init=init,
-            sanitize=cfg.sanitize)
-        jax.block_until_ready(sol.batch.a)
-        t1 = time.perf_counter()
-        if cfg.warm_start:
-            self._metro_duals.put(metro_id, sol.resume)
-        self.buckets_used.add(bucket_n)
-        self.stats.record_metro(t1 - t0, sol.outer_iters,
-                                warm=init is not None,
-                                hit_cap=sol.hit_iter_cap)
+        trace = TraceAnnotation.is_enabled()
+        with _span(trace, SPAN_METRO):
+            t0 = time.perf_counter()
+            n_cells = metro.n_cells
+            bucket_c = _next_pow2(n_cells)
+            bucket_n = _next_pow2(metro.cells.n_max, cfg.min_device_bucket)
+            with _span(trace, SPAN_METRO_PAD):
+                padded = pad_metro(metro, n_cells=bucket_c, n_max=bucket_n)
+            per_round = padded.cells.problem.fading is not None
+            i_shape = (bucket_c, padded.cells.problem.fading.shape[-1]) \
+                if per_round else (bucket_c,)
+            init: Optional[CoupledDuals] = \
+                self._metro_duals.get(metro_id) if cfg.warm_start else None
+            if init is not None and np.shape(init.interference) != i_shape:
+                init = None               # metro resized: run cold
+            sol = solve_coupled_core(
+                padded, outer_iters=outer_iters, outer_tol=outer_tol,
+                damping=damping, method=cfg.method,
+                power_solver=cfg.power_solver, eps=cfg.eps,
+                max_iters=cfg.max_iters, warm_start=cfg.warm_start,
+                init=init, sanitize=cfg.sanitize)
+            jax.block_until_ready(sol.batch.a)
+            t1 = time.perf_counter()
+            if cfg.warm_start:
+                self._metro_duals.put(metro_id, sol.resume)
+            self.buckets_used.add(bucket_n)
+            self.stats.record_metro(t1 - t0, sol.outer_iters,
+                                    warm=init is not None,
+                                    hit_cap=sol.hit_iter_cap)
         return CoupledResponse(metro_id=metro_id, solution=sol,
                                n_cells=n_cells,
                                warm_started=init is not None,
