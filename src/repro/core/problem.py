@@ -354,27 +354,16 @@ class WirelessFLProblem:
         poisoning the fused while-loop with NaN/Inf; healthy rows pass
         through bit-for-bit (``where`` with an all-True mask is the
         identity).  ``health`` defaults to :meth:`health_mask`.
+
+        The hardening is one compiled program (:func:`_sanitize_leaves`)
+        per tree of leaves and their shapes, not one dispatch per
+        primitive; inside an enclosing ``jit`` it is inlined.
         """
-        if health is None:
-            health = self.health_mask()
-        health = jnp.asarray(health, bool)
-        repl = {}
-        for name, fill in NEUTRAL_FILLS.items():
-            x = getattr(self, name)
-            repl[name] = jnp.where(health, x, jnp.asarray(fill, x.dtype))
-        rank = self.distance_m.ndim
-        if self.fading is not None:
-            h = health[..., None] if self.fading.ndim > rank else health
-            repl["fading"] = jnp.where(h, self.fading, _FADING_FILL)
-        if self.interference is not None:
-            h = (health[..., None] if self.interference.ndim > rank
-                 else health)
-            repl["interference"] = jnp.where(h, self.interference,
-                                             _INTERFERENCE_FILL)
-        if self.bits is not None:
-            h = health[..., None] if self.bits.ndim > rank else health
-            repl["bits"] = jnp.where(h, self.bits, _BITS_FILL)
-        return dataclasses.replace(self, **repl), health
+        leaves = {f.name: getattr(self, f.name)
+                  for f in dataclasses.fields(self)
+                  if not f.metadata.get("static")}
+        leaves, health = _sanitize_leaves(leaves, health)
+        return dataclasses.replace(self, **leaves), health
 
     def validate(self) -> None:
         """Raise ``ValueError`` naming the unhealthy devices, if any.
@@ -390,6 +379,34 @@ class WirelessFLProblem:
                 f"out-of-domain constraint data (flat indices "
                 f"{bad[:8].tolist()}{'...' if bad.size > 8 else ''}); "
                 "sanitize() degrades them to self-deselecting no-ops")
+
+
+@jax.jit
+def _sanitize_leaves(leaves: dict, health: Optional[jax.Array]
+                     ) -> tuple[dict, jax.Array]:
+    """:meth:`WirelessFLProblem.sanitize` over the problem's array leaves.
+
+    The static constants do not enter the hardening, so they are left
+    out of the program's key: one program per set of present optional
+    leaves and their shapes and dtypes.
+    """
+    problem = WirelessFLProblem(**leaves)
+    if health is None:
+        health = problem.health_mask()
+    health = jnp.asarray(health, bool)
+    repl = {}
+    for name, fill in NEUTRAL_FILLS.items():
+        x = leaves[name]
+        repl[name] = jnp.where(health, x, jnp.asarray(fill, x.dtype))
+    rank = problem.distance_m.ndim
+    for name, fill in (("fading", _FADING_FILL),
+                       ("interference", _INTERFERENCE_FILL),
+                       ("bits", _BITS_FILL)):
+        x = leaves[name]
+        if x is not None:
+            h = health[..., None] if x.ndim > rank else health
+            repl[name] = jnp.where(h, x, fill)
+    return repl, health
 
 
 def _bcast_like(x: jax.Array, rank: int) -> jax.Array:

@@ -733,7 +733,7 @@ class FleetControlService:
                 health = problem.health_mask(xp=np)
                 if not health.all():
                     n_unhealthy = int(health.size) - int(health.sum())
-                    problem, _ = problem.sanitize(health=jnp.asarray(health))
+                    problem, _ = problem.sanitize(health=health)
             fkey = None
             if cfg.warm_start:
                 with _span(trace, SPAN_KEY):
@@ -863,7 +863,7 @@ class FleetControlService:
             # problem, so the seed must be too
             health = problem.health_mask(xp=np)
             if not health.all():
-                problem, _ = problem.sanitize(health=jnp.asarray(health))
+                problem, _ = problem.sanitize(health=health)
         fkey = quantized_problem_key(problem, self.config.quant_decimals)
         state = WarmStart(a=jnp.asarray(solution.a),
                           power=jnp.asarray(solution.power))

@@ -246,6 +246,63 @@ def test_reference_in_bfloat16_is_far_off(seeded_metro):
     assert np.max(np.abs(np.asarray(ba, np.float64) - ra)) > 1e-3
 
 
+# --------------------------------------------------------------- sanitize
+
+def _seeded_metro_tick(seeded_metro, k=0):
+    _, _, dev, gains, statics, coupling, budget = seeded_metro
+    c, n = dev["weights"].shape
+    cells = stack_problems([
+        WirelessFLProblem(**{key: v[i] for key, v in dev.items()},
+                          fading=None, n_rounds=1, **statics)
+        for i in range(c)])
+    cells = dataclasses.replace(cells, problem=dataclasses.replace(
+        cells.problem, fading=gains[:, :, k:k + 1]))
+    return make_multicell(cells, coupling, backhaul_bits=budget)
+
+
+def test_sanitize_is_bitwise_identity_on_a_healthy_metro(seeded_metro):
+    metro = _seeded_metro_tick(seeded_metro)
+    plain = solve_coupled(metro, sanitize=False)
+    hardened = solve_coupled(metro, sanitize=True)
+    for name in ("a", "power"):
+        got = np.asarray(getattr(hardened.batch, name))
+        want = np.asarray(getattr(plain.batch, name))
+        assert got.tobytes() == want.tobytes(), name
+    assert np.asarray(hardened.mu).tobytes() == np.asarray(plain.mu).tobytes()
+    assert hardened.solved_at.tobytes() == plain.solved_at.tobytes()
+    assert hardened.outer_iters == plain.outer_iters
+
+
+def test_sanitize_deselects_a_poisoned_device(seeded_metro):
+    metro = _seeded_metro_tick(seeded_metro)
+    fading = np.array(metro.cells.problem.fading)
+    fading[3, 5, 0] = np.nan
+    cells = dataclasses.replace(metro.cells, problem=dataclasses.replace(
+        metro.cells.problem, fading=fading))
+    sol = solve_coupled(dataclasses.replace(metro, cells=cells),
+                        sanitize=True)
+    a = np.asarray(sol.batch.a)
+    p = np.asarray(sol.batch.power)
+    assert a[3, 5, 0] == 0.0 and p[3, 5, 0] == 0.0
+    assert np.all(np.isfinite(a)) and np.all(np.isfinite(p))
+    assert np.all(np.isfinite(sol.solved_at))
+    assert np.all(np.isfinite(sol.interference))
+    assert np.all(np.isfinite(np.asarray(sol.mu)))
+    # the other devices are still served
+    assert np.count_nonzero(a) > 0
+
+
+def test_second_service_tick_compiles_nothing(seeded_metro):
+    from repro.analysis.recompile import CompileBudget
+
+    svc = FleetControlService(ServiceConfig())
+    svc.solve_coupled("m", _seeded_metro_tick(seeded_metro, 0))
+    with CompileBudget(None) as budget:
+        resp = svc.solve_coupled("m", _seeded_metro_tick(seeded_metro, 1))
+    assert resp.warm_started
+    assert budget.count == 0, budget.names
+
+
 # -------------------------------------------------------------- solved at
 
 @pytest.fixture(scope="module")
